@@ -75,7 +75,7 @@ def to_markdown(rows) -> str:
 
 def update_kernel_rows(n_params_list, repeats: int = 3):
     """Roofline rows for the fused FL-update kernels: analytic TPU terms
-    (HBM/flops constants from repro.launch.mesh — elementwise kernels,
+    (v5e peaks from repro.launch.mesh.CHIP_PEAKS — elementwise kernels,
     so memory-bound by construction) plus a measured interpret-mode
     wall time on this host for the local_step kernel."""
     import jax
@@ -83,7 +83,8 @@ def update_kernel_rows(n_params_list, repeats: int = 3):
 
     from benchmarks.common import time_best_of
     from repro.kernels import ops
-    from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+    from repro.launch.mesh import V5E, chip_peaks
+    peaks = chip_peaks(V5E)
 
     rows = []
     for n in n_params_list:
@@ -92,8 +93,8 @@ def update_kernel_rows(n_params_list, repeats: int = 3):
             flops = 8 * n          # ~a handful of FMA-class ops per elem
             row = {"kernel": name, "n_params": n,
                    "bytes": bytes_moved, "flops": flops,
-                   "t_compute_s": flops / PEAK_FLOPS_BF16,
-                   "t_memory_s": bytes_moved / HBM_BW,
+                   "t_compute_s": flops / peaks.flops_bf16,
+                   "t_memory_s": bytes_moved / peaks.hbm_bw,
                    "bottleneck": "memory"}
             if name == "local_step":
                 p = jnp.zeros((n,), jnp.float32)

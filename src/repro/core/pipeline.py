@@ -116,11 +116,13 @@ class ScheduleResult:
 def run_phase_schedule(task: Task, data: FederatedDataset,
                        phases: Sequence[Phase],
                        verbose: bool = False,
-                       ledger: Optional[CommLedger] = None) -> ScheduleResult:
+                       ledger: Optional[CommLedger] = None,
+                       init_params: Optional[Any] = None) -> ScheduleResult:
     """Run ``phases`` in order, each starting from the previous phase's
-    final params, under one communication ledger."""
+    final params, under one communication ledger.  The first phase
+    starts from ``init_params`` (default: its own seeded init)."""
     ledger = ledger if ledger is not None else CommLedger()
-    params = None
+    params = init_params
     results: List[PhaseResult] = []
     for ph in phases:
         _, runner = _lookup_runner(ph.cfg)

@@ -157,7 +157,7 @@ def topk_threshold(d: jnp.ndarray, k: int) -> jnp.ndarray:
     """τ = the k-th largest |d| (traced), the exact value
     ``np.partition(|d|, n-k)[n-k]`` selects.  Appending zero padding to
     ``d`` never changes τ as long as ``k`` counts LOGICAL elements, so
-    callers may pass GRID_ALIGN-padded buffers with a logical ``k``.
+    callers may pass carry-padded buffers with a logical ``k``.
 
     Selection runs as a 31-step binary search over the IEEE-754 bit
     space: |x| is non-negative, so its uint32 pattern orders like the

@@ -542,10 +542,10 @@ class SparseClientStateStore:
             live = evicted >= 0
             if self.spill and np.any(live):
                 rows = tree_rows(table, jnp.asarray(victims[live]))
-                try:                    # cold rows park on the CPU device
-                    rows = jax.device_put(rows, jax.devices("cpu")[0])
-                except RuntimeError:
-                    pass                # no CPU device: plain device refs
+                # cold rows park on the CPU device; without one (JAX
+                # started with a platform list that leaves CPU out)
+                # jax.devices raises — spilled rows never stay in HBM
+                rows = jax.device_put(rows, jax.devices("cpu")[0])
                 block = _SpillBlock(jax.tree_util.tree_leaves(rows))
                 # eager off-thread materialization: the conversion waits
                 # for the in-flight dispatch on the WORKER, not here
@@ -1246,6 +1246,9 @@ class EngineResult:
     # spill_materialize_ms = background spill→numpy conversion time
     # (work moved OFF the critical path, not added to it)
     timing: Optional[Dict[str, float]] = None
+    # per history row: host clock from its dispatch to drained losses
+    # (compilation included), split evenly over the chunk's rounds
+    round_wall_s: List[float] = dataclasses.field(default_factory=list)
 
 
 def make_chunk_fn(task: Task, strategy, schedule: RoundSchedule,
@@ -1510,13 +1513,14 @@ def run_rounds(task: Task, data: FederatedDataset, strategy,
         timing["host_residency_ms"] += (time.perf_counter() - t0) * 1e3
 
     history: List[Dict[str, float]] = []
+    round_wall_s: List[float] = []
     dispatches = 0
     plan = make_plan(0) if schedule.rounds > 0 else None
     staged_plan = None
     while plan is not None:
         if staged_plan is not plan:     # sync path (or the first chunk)
             stage(plan)
-        t0 = time.perf_counter()
+        t_dispatch = t0 = time.perf_counter()
         algo_state = strategy.commit_chunk_state(algo_state, plan.staged)
         key, params, algo_state, server_state, losses, metrics = chunk_fn(
             key, params, algo_state, server_state, x_all, y_all, n_real,
@@ -1534,7 +1538,8 @@ def run_rounds(task: Task, data: FederatedDataset, strategy,
 
         t0 = time.perf_counter()
         losses = np.asarray(losses)     # blocks: the dispatch drains here
-        timing["device_wait_ms"] += (time.perf_counter() - t0) * 1e3
+        t_done = time.perf_counter()
+        timing["device_wait_ms"] += (t_done - t0) * 1e3
         metrics = np.asarray(metrics) if metrics is not None else None
 
         rnd, R = plan.rnd, plan.R
@@ -1543,6 +1548,7 @@ def run_rounds(task: Task, data: FederatedDataset, strategy,
                 strategy.record(ledger, K, params, task)
             row = {"round": rnd + j, "local_loss": float(losses[j]),
                    "phase": phase}
+            round_wall_s.append((t_done - t_dispatch) / R)
             if plan.do_eval[j]:
                 row["acc"] = float(metrics[j])
                 if verbose:
@@ -1573,4 +1579,5 @@ def run_rounds(task: Task, data: FederatedDataset, strategy,
         # (n_clients, model) tree here would defeat the sparse store
     return EngineResult(params=params, history=history,
                         algo_state=algo_state, server_state=server_state,
-                        dispatches=dispatches, timing=timing)
+                        dispatches=dispatches, timing=timing,
+                        round_wall_s=round_wall_s)

@@ -52,7 +52,7 @@ from repro.fl.compression import (CompressionSpec, topk_k, topk_threshold,
 from repro.fl.privacy import DPSpec
 from repro.fl.task import Task
 from repro.kernels import ops
-from repro.kernels.fused_update import GRID_ALIGN
+from repro.kernels.fused_update import LANES, padded_len
 from repro.utils import tree_math as tm
 from repro.utils.flatten import FlatView
 
@@ -212,28 +212,22 @@ class FlatParamOps:
         frozen slots are definitionally zero (server moments, deltas)."""
         return self.view.unflatten(bufs, frozen)
 
-    @staticmethod
-    def _pad_len(n: int) -> int:
-        """Next GRID_ALIGN multiple ≥ n — the buffer length at which the
-        kernel wrappers' per-call row pad degenerates to a reshape."""
-        return -(-n // GRID_ALIGN) * GRID_ALIGN if n else 0
-
     @property
     def padded_sizes(self) -> Dict[str, int]:
-        """Per-bucket carried length: logical size rounded up to the
-        kernel grid, so every kernel call over a carried buffer hits the
-        pad==0 fast path."""
-        return {name: self._pad_len(size)
+        """Per-bucket carried length: the logical size rounded up to a
+        length both kernel grids (interpret and compiled) tile exactly,
+        so no kernel call over a carried buffer copies it to pad."""
+        return {name: padded_len(size)
                 for name, size in self.view.buffer_sizes.items()}
 
     def pad(self, bufs: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
-        """Right-pad each buffer's last axis up to the next GRID_ALIGN
-        multiple (no-op on already-padded buffers).  Pad lanes start —
-        and, by the kernel invariant, stay — zero, and unflatten reads
-        only the logical prefix, so padded buffers flow through every
-        dict-level op unchanged."""
+        """Right-pad each buffer's last axis up to its carried length
+        (no-op on already-padded buffers).  Pad lanes start — and, by
+        the kernel invariant, stay — zero, and unflatten reads only the
+        logical prefix, so padded buffers flow through every dict-level
+        op unchanged."""
         def _p(b):
-            target = self._pad_len(b.shape[-1])
+            target = padded_len(b.shape[-1])
             if target == b.shape[-1]:
                 return b
             widths = [(0, 0)] * (b.ndim - 1) + [(0, target - b.shape[-1])]
@@ -288,11 +282,12 @@ class FlatParamOps:
 
     def place_frozen(self, bufs: Dict[str, jnp.ndarray]
                      ) -> Dict[str, jnp.ndarray]:
-        """Commit the frozen constant bucket to its home placement.  NOT
-        padded — frozen buffers never enter the kernels (unflatten reads
-        the logical prefix only) — and NEVER donated: the same arrays
-        are closed over by every chunk of a phase.  Host: plain copy;
-        pod: device_put with the frozen-group shardings."""
+        """Commit the frozen constant bucket to its home placement.
+        NEVER donated: the same arrays are closed over by every chunk of
+        a phase.  Host: plain copy of the unpadded buckets (frozen
+        buffers never enter the kernels); pod: device_put of the tiled
+        buckets (``ShardedFlatOps.flatten_frozen``) with the
+        frozen-group shardings."""
         return jax.tree_util.tree_map(jnp.array, bufs)
 
     def frozen_shardings(self):
@@ -632,18 +627,36 @@ def make_local_fn(task: Task, spec: LocalSpec,
             return loss_for_variant(flat_ops.unflatten(p_bufs, frozen),
                                     extras, bx, by, rng)
 
-        grad_fn = jax.value_and_grad(flat_loss)
+        # the scan carries padded 1-D (host) buffers as (rows, 128)
+        # tiles, the kernels' own shape: vmapped over clients, a (K, n)
+        # carry is laid out on TPU in (K, 128) tiles, and every
+        # step-tail call would relayout the whole client stack in and
+        # out.  Pod buffers arrive tiled.
+        shapes = {name: b.shape for name, b in p_start.items()}
+
+        def tile(bufs):
+            return {k: b.reshape(-1, LANES)
+                    if b.ndim == 1 and b.shape[0] % LANES == 0 else b
+                    for k, b in bufs.items()}
+
+        def untile(bufs):
+            return {k: b.reshape(shapes[k]) for k, b in bufs.items()}
+
+        grad_fn = jax.value_and_grad(
+            lambda p_tiles, *a: flat_loss(untile(p_tiles), *a))
 
         def step(carry, step_key):
             p_bufs, m_bufs = carry
             bidx = jax.random.randint(step_key, (spec.batch_size,), 0, n_data)
             loss, g_bufs = grad_fn(p_bufs, cx[bidx], cy[bidx], step_key)
-            p_bufs, m_bufs = fused_step_tail(spec, flat_ops, p_bufs, g_bufs,
-                                             m_bufs, c_bufs, lr_scale)
-            return (p_bufs, m_bufs), loss
+            p_bufs, m_bufs = fused_step_tail(
+                spec, flat_ops, untile(p_bufs), untile(g_bufs),
+                untile(m_bufs), c_bufs, lr_scale)
+            return (tile(p_bufs), tile(m_bufs)), loss
 
         keys = jax.random.split(key, spec.n_steps)
-        (p_end, _), losses = jax.lax.scan(step, (p_start, m0), keys)
-        return p_end, {"loss": jnp.mean(losses)}
+        (p_end, _), losses = jax.lax.scan(step, (tile(p_start), tile(m0)),
+                                          keys)
+        return untile(p_end), {"loss": jnp.mean(losses)}
 
     return local_fused if fused else local_tree

@@ -79,12 +79,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 # canonical seed host-RNG stream offsets (P1 drew from seed+31, P2 from
@@ -109,6 +109,7 @@ from repro.fl.local import (
 from repro.fl.simulation import HOST_RNG_OFFSET_P2
 from repro.fl.task import Task
 from repro.kernels import ops
+from repro.kernels.fused_update import LANES, padded_len
 from repro.sharding import rules
 from repro.utils import tree_math as tm
 
@@ -309,12 +310,43 @@ class ShardedFlatOps(FlatParamOps):
     shard-local: :meth:`_run` wraps every fused-kernel call in a
     ``shard_map`` whose in/out specs are the bucket's
     ``flat_buffer_pspec`` — each device runs the blocked Pallas pass on
-    its own contiguous ``(1, per_shard)`` tile with zero collectives
-    (the only cross-shard communication in the whole update path is the
-    global clip norm, a scalar psum XLA inserts for
-    :meth:`FlatParamOps.grad_sqsum`).
+    its own contiguous tile with zero collectives (the only cross-shard
+    communication in the whole update path is the global clip norm, a
+    scalar psum XLA inserts for :meth:`FlatParamOps.grad_sqsum`).
+
+    Carried buffers are TILED: :meth:`pad` pads each per-shard row to
+    its carried length and splits it into ``(rows, 128)``, so a carry is
+    ``(n_shards, rows, 128)`` — the kernel grid's own shape.  A 2-D
+    ``(n_shards, per_shard)`` bf16 buffer would be laid out on TPU with
+    its one-row shard axis padded to a packed sublane pair (twice its
+    bytes), and every shard-local kernel call would relayout it into
+    tiles and back.  Frozen buckets, which no kernel reads, take the
+    same layout, so every pod bucket has one.  For the same reason
+    :meth:`unflatten` slices leaf tiles out of each device's own buffer
+    under ``shard_map`` (and packs the gradient back the same way)
+    instead of through the global ``(n_shards, per_shard)`` view.
     """
     mesh: Any = None
+
+    def flatten(self, tree: Pytree) -> Dict[str, jnp.ndarray]:
+        # packs straight into tiled carries, per device: the global
+        # (n_shards, per_shard) concatenate compiles for minutes at
+        # model width on TPU (180 s for qwen1.5-0.5b)
+        return _pack_on_mesh(self, tree)
+
+    def unflatten(self, bufs: Dict[str, jnp.ndarray],
+                  frozen: Optional[Dict[str, jnp.ndarray]] = None) -> Pytree:
+        if self.view.frozen_groups and not frozen:
+            frozen = self.frozen_zeros()
+        return _mesh_unflatten(self, bufs, frozen or {})
+
+    def pad(self, bufs: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
+        # fresh (n_shards, per_shard) buckets pad and tile; anything
+        # already tiled (or stacked over clients) passes through
+        flat = FlatParamOps.pad(self, {k: b for k, b in bufs.items()
+                                       if b.ndim == 2})
+        return {k: _tile(flat[k]) if k in flat else b
+                for k, b in bufs.items()}
 
     def place(self, bufs: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
         # pad each bucket's per-shard axis to the kernel grid (so the
@@ -341,12 +373,20 @@ class ShardedFlatOps(FlatParamOps):
         raise NotImplementedError("the pod backend aggregates "
                                   "sequentially — no stacked buffers")
 
+    def flatten_frozen(self, tree: Pytree) -> Dict[str, jnp.ndarray]:
+        # packed per device into the carries' tiled layout, like flatten
+        if not self.view.frozen_groups:
+            return {}
+        return _pack_on_mesh(self, tree, True)
+
+    def frozen_zeros(self) -> Dict[str, jnp.ndarray]:
+        return self.pad(self.view.frozen_zeros())
+
     def place_frozen(self, bufs: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
-        # frozen buckets never enter a kernel, so no pad — just pin the
-        # read-only constants to their mesh layout (replicated or FSDP
-        # per the group's axes) with the same unaliased-copy guard as
-        # place(): these live OUTSIDE the donated carry but must not
-        # alias a caller's array either.
+        # pin the (already tiled) read-only constants to their mesh
+        # layout (replicated or FSDP per the group's axes) with the same
+        # unaliased-copy guard as place(): these live OUTSIDE the
+        # donated carry but must not alias a caller's array either.
         placed = jax.device_put(bufs, self.frozen_shardings())
         return jax.tree_util.tree_map(
             lambda orig, out: jnp.copy(out) if out is orig else out,
@@ -365,8 +405,9 @@ class ShardedFlatOps(FlatParamOps):
         scalars = tuple(jnp.asarray(s, jnp.float32) if not hasattr(s, "dtype")
                         else s for s in scalars)
         # per-shard length from the buffer itself, not group.size — the
-        # carried buffers are pre-padded to the kernel grid
-        local = [jax.ShapeDtypeStruct((b.shape[-1],), b.dtype) for b in bufs]
+        # carried buffers are pre-padded (and tiled) to the kernel grid
+        local = [jax.ShapeDtypeStruct((math.prod(b.shape[1:]),), b.dtype)
+                 for b in bufs]
         sc_specs = [jax.ShapeDtypeStruct(jnp.shape(s), s.dtype)
                     for s in scalars]
         n_out = len(jax.eval_shape(fn, *local, *sc_specs))
@@ -374,12 +415,12 @@ class ShardedFlatOps(FlatParamOps):
         def body(*args):
             bs, sc = args[:len(bufs)], args[len(bufs):]
             outs = fn(*[b.reshape(-1) for b in bs], *sc)
-            return tuple(o.reshape(1, -1) for o in outs)
+            return tuple(o.reshape(bs[0].shape) for o in outs)
 
-        run = shard_map(body, mesh=self.mesh,
-                        in_specs=tuple([bspec] * len(bufs) +
-                                       [P()] * len(scalars)),
-                        out_specs=(bspec,) * n_out, check_rep=False)
+        run = jax.shard_map(body, mesh=self.mesh,
+                            in_specs=tuple([bspec] * len(bufs) +
+                                           [P()] * len(scalars)),
+                            out_specs=(bspec,) * n_out, check_vma=False)
         return run(*bufs, *scalars)
 
     def _logical_size(self, name: str) -> int:
@@ -431,9 +472,9 @@ class ShardedFlatOps(FlatParamOps):
                                         None, c_loc[0], interpret=interpret)
             return out.reshape(a_loc.shape)
 
-        run = shard_map(body, mesh=self.mesh,
-                        in_specs=(lane_spec, lane_spec, P(rules.DATA)),
-                        out_specs=lane_spec, check_rep=False)
+        run = jax.shard_map(body, mesh=self.mesh,
+                            in_specs=(lane_spec, lane_spec, P(rules.DATA)),
+                            out_specs=lane_spec, check_vma=False)
         return {name: run(acc, w_bufs[name], coeffs)
                 for name, acc in acc_bufs.items()}
 
@@ -447,9 +488,124 @@ class ShardedFlatOps(FlatParamOps):
         def body(a_loc):
             return jax.lax.psum(jnp.sum(a_loc, axis=0), rules.DATA)
 
-        run = shard_map(body, mesh=self.mesh, in_specs=(lane_spec,),
-                        out_specs=P(None, None), check_rep=False)
+        run = jax.shard_map(body, mesh=self.mesh, in_specs=(lane_spec,),
+                            out_specs=P(None, None), check_vma=False)
         return {name: run(acc) for name, acc in acc_bufs.items()}
+
+
+# -- leaf tiles on the mesh ---------------------------------------------------
+#
+# Row k of a bucket is device k's FSDP×TP tile of every leaf in it,
+# flattened row-major at the leaf's static offset, so under shard_map a
+# device's local leaf block is a 1-D slice of its own buffer, reshaped.
+# Unflatten and its transpose (packing the leaf gradients) therefore run
+# shard-locally with 1-D intermediates only; custom_vjp states the
+# transpose directly rather than transposing a shard_map.
+
+def _slot_pspec(slot) -> P:
+    return P(*[(e[0] if len(e) == 1 else e) if e else None
+               for e in slot.dim_axes])
+
+
+def _local_shape(view, slot) -> tuple:
+    sizes = dict(view.axis_sizes)
+    return tuple(d // math.prod(sizes[a] for a in e)
+                 for d, e in zip(slot.shape, slot.dim_axes))
+
+
+def _bucket_specs(fops: "ShardedFlatOps", names) -> Dict[str, P]:
+    return {name: rules.flat_buffer_pspec(fops.view.group_map[name])
+            for name in names}
+
+
+def _leaf_specs(view) -> Pytree:
+    return jax.tree_util.tree_unflatten(
+        view.treedef, [_slot_pspec(s) for s in view.slots])
+
+
+def _lane_aligned(slot) -> bool:
+    return slot.offset % LANES == 0 and slot.size % LANES == 0
+
+
+def _unflatten_on_mesh(fops: "ShardedFlatOps", bufs, frozen) -> Pytree:
+    view = fops.view
+
+    def leaf(buf, s):
+        # a lane-aligned leaf is a row range of the tiled block: no
+        # (…, n) intermediate even when a vmap batches this body
+        if _lane_aligned(s):
+            r0 = s.offset // LANES
+            tile = buf[0, r0:r0 + s.size // LANES]
+        else:
+            tile = buf.reshape(-1)[s.offset:s.offset + s.size]
+        return tile.reshape(_local_shape(view, s))
+
+    def body(b, fz):
+        merged = {**b, **fz}
+        leaves = [leaf(merged[s.buffer], s) for s in view.slots]
+        return jax.tree_util.tree_unflatten(view.treedef, leaves)
+
+    return jax.shard_map(
+        body, mesh=fops.mesh,
+        in_specs=(_bucket_specs(fops, bufs), _bucket_specs(fops, frozen)),
+        out_specs=_leaf_specs(view), check_vma=False)(bufs, frozen)
+
+
+def _flatten_on_mesh(fops: "ShardedFlatOps", tree: Pytree,
+                     frozen: bool = False):
+    """Pack ``tree``'s trainable (or, with ``frozen``, its frozen)
+    leaves into tiled buckets — the transpose of
+    :func:`_unflatten_on_mesh`."""
+    view = fops.view
+    groups = view.frozen_groups if frozen else view.trainable_groups
+    names = [g.name for g in groups]
+
+    def body(t):
+        parts: Dict[str, list] = {}
+        for s, leaf in zip(view.slots, jax.tree_util.tree_leaves(t)):
+            if s.buffer in names:
+                parts.setdefault(s.buffer, []).append((s, leaf))
+        out = {}
+        for g in groups:
+            pad = padded_len(g.size) - g.size
+            if all(_lane_aligned(s) for s, _ in parts[g.name]):
+                rows = jnp.concatenate([x.reshape(-1, LANES)
+                                        for _, x in parts[g.name]])
+                rows = jnp.pad(rows, ((0, pad // LANES), (0, 0)))
+            else:
+                flat = jnp.concatenate([x.reshape(-1)
+                                        for _, x in parts[g.name]])
+                rows = _tile(jnp.pad(flat, (0, pad)))
+            out[g.name] = rows.astype(g.dtype)[None]
+        return out
+
+    return jax.shard_map(
+        body, mesh=fops.mesh, in_specs=(_leaf_specs(view),),
+        out_specs=_bucket_specs(fops, names), check_vma=False)(tree)
+
+
+_pack_on_mesh = jax.jit(_flatten_on_mesh, static_argnums=(0, 2))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _mesh_unflatten(fops, bufs, frozen):
+    return _unflatten_on_mesh(fops, bufs, frozen)
+
+
+def _mesh_unflatten_fwd(fops, bufs, frozen):
+    return _unflatten_on_mesh(fops, bufs, frozen), None
+
+
+def _mesh_unflatten_bwd(fops, _, ct):
+    return _flatten_on_mesh(fops, ct), None       # frozen: no gradient
+
+
+_mesh_unflatten.defvjp(_mesh_unflatten_fwd, _mesh_unflatten_bwd)
+
+
+def _tile(b: jnp.ndarray) -> jnp.ndarray:
+    """``(..., n)`` → ``(..., n // 128, 128)`` (n is a carried length)."""
+    return b.reshape(b.shape[:-1] + (b.shape[-1] // LANES, LANES))
 
 
 @functools.lru_cache(maxsize=32)

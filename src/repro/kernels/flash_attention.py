@@ -17,9 +17,9 @@ Dynamic quantities ride in a scalar-prefetch operand (SMEM):
                   per-layer in hybrid models (Hymba SWA/global mix)
   [2] kv_len    — true #keys before padding to a bk multiple
 
-This container is CPU-only: the kernel is validated in interpret mode
-against ``ref.attention_ref``; on real TPU the same code lowers to
-Mosaic (pallas_call is the TARGET artifact).
+The tests validate the kernel in interpret mode on CPU against
+``ref.attention_ref``; on a TPU the same code lowers to Mosaic.  It has
+no backward pass, so training paths use XLA attention.
 """
 from __future__ import annotations
 

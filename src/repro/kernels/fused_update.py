@@ -49,14 +49,19 @@ disabled terms cost nothing.
 Buffers are 1-D; the wrappers pad to a (rows, 128) grid of
 ``block_rows``-row tiles and strip the pad on return — pad lanes stay
 zero through every op above, so chaining kernels over padded buffers is
-safe.  Callers that carry buffers across many kernel calls (the
-engine's FlatParamOps chunk carries) pre-pad them to ``GRID_ALIGN``
-(one 8-sublane × 128-lane tile) once at placement time: ``_pad_rows``
-then degenerates to a reshape on every call, so the interpret/CPU path
-pays zero pad copies per operand per step, and the trailing ``[:n]``
-strip is a no-op slice XLA folds.  This container is CPU-only: the
-kernels are validated in interpret mode against the tree_math oracles
-(tests/test_fused_update); on TPU the same code lowers to Mosaic.
+safe.  A wrapper whose operand length is not already on the grid pays a
+whole-buffer pad copy per operand (and a slice copy per output), which
+at model scale costs more HBM than the kernel itself.  Callers that
+carry buffers across many kernel calls (the engine's FlatParamOps chunk
+carries, the pod's per-shard buckets) therefore pre-pad them once, at
+placement time, to :func:`padded_len` — a length on BOTH grids the
+wrappers use: the one-block grid of interpret mode and the
+``DEFAULT_BLOCK_ROWS``-row grid Mosaic compiles on TPU.  ``_pad_rows``
+is then a reshape and the trailing ``[:n]`` strip a no-op on every
+call.  The kernels lower to Mosaic on TPU; with ``interpret=True`` the
+same code runs in the Pallas interpreter on any backend, which is how
+the tests check them against the tree_math oracles on CPU
+(tests/test_fused_update.py).
 """
 from __future__ import annotations
 
@@ -70,10 +75,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 DEFAULT_BLOCK_ROWS = 512        # 512×128 f32 = 256 KB per operand tile
-# one (8, 128) sublane×lane tile — buffers whose length is a multiple of
-# this hit the pad==0 fast path in _pad_rows on the one-block interpret
-# grid (FlatParamOps pre-pads its carried buffers to this alignment)
+# one (8, 128) sublane×lane tile: the row alignment of both grids
 GRID_ALIGN = 8 * LANES
+
+
+def padded_len(n: int, block_rows: int = DEFAULT_BLOCK_ROWS) -> int:
+    """Smallest length ≥ n that :func:`_grid_rows` tiles with no pad,
+    interpreted or compiled: a GRID_ALIGN multiple while the buffer fits
+    one ``block_rows`` block (the block clamps to the buffer), else a
+    multiple of the whole ``block_rows × 128`` block."""
+    if n == 0:
+        return 0
+    align = GRID_ALIGN if n <= block_rows * LANES else block_rows * LANES
+    return -(-n // align) * align
 
 
 def _grid_rows(n: int, block_rows: int, interpret: bool) -> Tuple[int, int]:

@@ -55,9 +55,12 @@ def ssd_with_state(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
 #
 # The FL layers call these through a ``repro.fl.local.FlatParamOps``
 # (flat-first: one call per dtype/mesh-axis bucket) with
-# ``interpret=fused_interpret(spec)``, so ``update_impl="fused"`` lowers
-# to Mosaic on TPU and transparently runs the interpreter on the CPU
-# container (where there is no Mosaic backend);
+# ``interpret=fused_interpret(update_impl)``.  ``update_impl="fused"``
+# lowers to Mosaic whenever the backend is a TPU; on any other backend
+# Mosaic cannot lower, so it runs the Pallas interpreter — the CPU test
+# path.  The entry points (``repro.launch.train``, ``chip_smoke.py``)
+# print the resolution, and ``chip_smoke.py`` refuses to run off-TPU,
+# so a chip run can never pass on the interpreter.
 # ``update_impl="fused_interpret"`` forces the interpreter everywhere
 # (parity tests, benchmarks).  All wrappers take 1-D buffers: on the
 # pod, ``repro.fl.pod.ShardedFlatOps`` invokes them inside a
@@ -65,10 +68,23 @@ def ssd_with_state(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
 # kernels serve single-host FlatView buffers and mesh-sharded
 # ShardedFlatView buckets unchanged.
 
+@functools.cache
+def _backend_is_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def fused_interpret(update_impl: str) -> bool:
     """interpret= flag for an ``update_impl`` value: explicit interpret
-    mode, or a CPU/GPU backend where Mosaic cannot lower."""
-    return update_impl == "fused_interpret" or jax.default_backend() != "tpu"
+    mode, or a backend other than TPU, where Mosaic cannot lower.  The
+    backend is read once per process."""
+    return update_impl == "fused_interpret" or not _backend_is_tpu()
+
+
+def describe_fused(update_impl: str) -> str:
+    """One line naming the backend and the interpret flag the fused
+    path resolved to — what the entry points print."""
+    return (f"backend={jax.default_backend()} update_impl={update_impl} "
+            f"interpret={fused_interpret(update_impl)}")
 
 
 @functools.partial(jax.jit, static_argnames=("weight_decay", "momentum",

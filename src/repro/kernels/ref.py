@@ -74,3 +74,72 @@ def ssd_ref(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
          jnp.moveaxis(Bh, 1, 0).astype(jnp.float32),
          jnp.moveaxis(Ch, 1, 0).astype(jnp.float32)))
     return jnp.moveaxis(ys, 0, 1).astype(x.dtype), final
+
+
+# ---------------------------------------------------------------------------
+# fused FL-update kernels (repro.kernels.fused_update) — same op order,
+# f32 compute, cast on store
+# ---------------------------------------------------------------------------
+
+def local_step_ref(p, g, m, c, clip_scale, step_size, *,
+                   weight_decay: float = 0.0, momentum: float = 0.0):
+    """Oracle of ``fused_update.local_step``: ``(p_new, m_new)``."""
+    p32 = p.astype(jnp.float32)
+    g = g.astype(jnp.float32) * clip_scale
+    if c is not None:
+        g = g + c.astype(jnp.float32)
+    if weight_decay:
+        g = g + weight_decay * p32
+    m_new = None
+    if m is not None:
+        m32 = g + momentum * m.astype(jnp.float32)
+        m_new, g = m32.astype(m.dtype), m32
+    return (p32 - step_size * g).astype(p.dtype), m_new
+
+
+def weighted_delta_ref(stacked, p, weights, extra=None, *,
+                       deltas: bool = False):
+    """Oracle of ``fused_update.weighted_delta``."""
+    p32 = p.astype(jnp.float32)
+    w32 = weights.astype(jnp.float32)
+    acc = jnp.zeros_like(p32) if extra is None else extra
+    for k in range(stacked.shape[0]):       # the kernel's summation order
+        s32 = stacked[k].astype(jnp.float32)
+        acc = acc + w32[k] * (s32 if deltas else s32 - p32)
+    return (p32 + acc).astype(p.dtype)
+
+
+def delta_accum_ref(delta, w_end, p, coeff):
+    """Oracle of ``fused_update.delta_accum`` (``p=None``: accum-only)."""
+    w32 = w_end.astype(jnp.float32)
+    if p is not None:
+        w32 = w32 - p.astype(jnp.float32)
+    return delta + coeff * w32
+
+
+def dp_clip_noise_ref(d, z, clip_scale, noise_scale):
+    """Oracle of ``fused_update.dp_clip_noise``."""
+    u = clip_scale * d.astype(jnp.float32)
+    return u if z is None else u + noise_scale * z
+
+
+def server_update_ref(p, delta, moments, scalars, *, opt: str = "none",
+                      beta: float = 0.9, b1: float = 0.9, b2: float = 0.99,
+                      eps: float = 1e-8):
+    """Oracle of ``fused_update.server_update``: ``(p_new, moments)``."""
+    p32 = p.astype(jnp.float32)
+    if opt == "none":
+        return (p32 + delta).astype(p.dtype), ()
+    g = -delta
+    lr = scalars[0]
+    if opt == "momentum":
+        (m,) = moments
+        m32 = beta * m.astype(jnp.float32) + g
+        return (p32 - lr * m32).astype(p.dtype), (m32.astype(m.dtype),)
+    mu, nu = moments
+    bc1, bc2 = scalars[1], scalars[2]
+    mu32 = b1 * mu.astype(jnp.float32) + (1.0 - b1) * g
+    nu32 = b2 * nu.astype(jnp.float32) + (1.0 - b2) * g * g
+    u = (mu32 / bc1) / (jnp.sqrt(nu32 / bc2) + eps)
+    return ((p32 - lr * u).astype(p.dtype),
+            (mu32.astype(mu.dtype), nu32.astype(nu.dtype)))

@@ -115,7 +115,8 @@ def _scan_cost_correction(cfg, shape, mesh, n_chips, *, n_micro=1,
                                 n_micro=n_micro, layout=layout)
         compiled = lowered.compile()
         t = analyze_compiled(compiled, arch=cfg.name, shape=shape.name,
-                             mesh_name="corr", n_chips=n_chips)
+                             mesh_name="corr", n_chips=n_chips,
+                             device_kind=meshlib.V5E)
         costs.append((t.hlo_flops, t.hlo_bytes, t.collective_bytes))
     (f1, b1, c1), (f2, b2, c2) = costs
     L = cfg.n_layers - cfg.n_dense_layers
@@ -145,7 +146,7 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool = False,
             t_compile = time.time() - t0 - t_lower
         terms = analyze_compiled(
             compiled, arch=arch, shape=shape_name, mesh_name=mesh_name,
-            n_chips=n_chips,
+            n_chips=n_chips, device_kind=meshlib.V5E,
             model_flops=model_flops_estimate(cfg, shape))
         if correct_scan and cfg.n_layers > cfg.n_dense_layers + 1:
             raw = (terms.hlo_flops, terms.hlo_bytes, terms.collective_bytes)

@@ -2,9 +2,12 @@
 
 Three terms per (arch × shape × mesh), per DESIGN.md §6:
 
-    compute    = HLO_FLOPs_total        / (chips · PEAK_FLOPS_BF16)
-    memory     = HLO_bytes_total        / (chips · HBM_BW)
-    collective = collective_bytes_total / (chips · ICI_BW)
+    compute    = HLO_FLOPs_total        / (chips · peak bf16 FLOP/s)
+    memory     = HLO_bytes_total        / (chips · HBM bandwidth)
+    collective = collective_bytes_total / (chips · ICI bandwidth/link)
+
+The rates are the target chip's published peaks
+(``repro.launch.mesh.chip_peaks``, keyed by ``device_kind``).
 
 IMPORTANT: for an SPMD-partitioned module, ``compiled.cost_analysis()``
 and the HLO text describe the PER-DEVICE program, so the measured FLOPs
@@ -23,7 +26,7 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import ChipPeaks, chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -85,11 +88,6 @@ def total_collective_bytes(hlo_text: str) -> int:
 
 
 def _cost_value(cost, key: str) -> float:
-    """cost_analysis() is a dict (new jax) or [dict] (older)."""
-    if cost is None:
-        return 0.0
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     return float(cost.get(key, 0.0))
 
 
@@ -99,6 +97,7 @@ class RooflineTerms:
     shape: str
     mesh: str
     n_chips: int
+    device_kind: str            # target chip, a key of mesh.CHIP_PEAKS
     hlo_flops: float            # PER CHIP (post-SPMD module)
     hlo_bytes: float            # PER CHIP bytes accessed
     collective_bytes: int       # PER CHIP ICI bytes
@@ -107,17 +106,21 @@ class RooflineTerms:
     bytes_per_device: Optional[Dict[str, float]] = None
 
     @property
+    def peaks(self) -> ChipPeaks:
+        return chip_peaks(self.device_kind)
+
+    @property
     def t_compute(self) -> float:
-        return self.hlo_flops / PEAK_FLOPS_BF16
+        return self.hlo_flops / self.peaks.flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / HBM_BW
+        return self.hlo_bytes / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
         # collective_bytes is already per-chip
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -133,7 +136,7 @@ class RooflineTerms:
     def to_dict(self) -> dict:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
-            "n_chips": self.n_chips,
+            "n_chips": self.n_chips, "device_kind": self.device_kind,
             "hlo_flops_per_chip": self.hlo_flops,
             "hlo_bytes_per_chip": self.hlo_bytes,
             "collective_bytes_per_chip": self.collective_bytes,
@@ -150,7 +153,10 @@ class RooflineTerms:
 
 
 def analyze_compiled(compiled, *, arch: str, shape: str, mesh_name: str,
-                     n_chips: int, model_flops: float = 0.0) -> RooflineTerms:
+                     n_chips: int, device_kind: str,
+                     model_flops: float = 0.0) -> RooflineTerms:
+    """Roofline terms of ``compiled`` against ``device_kind``'s peaks."""
+    chip_peaks(device_kind)             # unknown chip: fail before work
     cost = compiled.cost_analysis()
     flops = _cost_value(cost, "flops")
     byts = _cost_value(cost, "bytes accessed")
@@ -158,22 +164,22 @@ def analyze_compiled(compiled, *, arch: str, shape: str, mesh_name: str,
     det = collective_bytes_by_op(hlo)
     coll = sum(v for k, v in det.items() if not k.startswith("_"))
 
-    mem = None
-    try:
-        ma = compiled.memory_analysis()
-        mem = {
-            "argument_bytes": float(getattr(ma, "argument_size_in_bytes", 0)),
-            "output_bytes": float(getattr(ma, "output_size_in_bytes", 0)),
-            "temp_bytes": float(getattr(ma, "temp_size_in_bytes", 0)),
-            "peak_bytes": float(
-                getattr(ma, "peak_memory_in_bytes",
-                        getattr(ma, "temp_size_in_bytes", 0))),
-        }
-    except Exception:  # pragma: no cover - backend-specific
-        pass
+    ma = compiled.memory_analysis()
+    mem = {
+        "argument_bytes": float(ma.argument_size_in_bytes),
+        "output_bytes": float(ma.output_size_in_bytes),
+        "alias_bytes": float(ma.alias_size_in_bytes),
+        "temp_bytes": float(ma.temp_size_in_bytes),
+        # live at once: arguments + outputs not aliased to them + temps
+        "peak_bytes": float(ma.argument_size_in_bytes
+                            + ma.output_size_in_bytes
+                            - ma.alias_size_in_bytes
+                            + ma.temp_size_in_bytes),
+    }
 
     return RooflineTerms(
         arch=arch, shape=shape, mesh=mesh_name, n_chips=n_chips,
+        device_kind=device_kind,
         hlo_flops=flops, hlo_bytes=byts, collective_bytes=coll,
         collective_detail=det, model_flops=model_flops,
         bytes_per_device=mem)

@@ -1,25 +1,64 @@
-"""Production meshes.
+"""Meshes and per-chip peak rates.
 
-Target hardware: TPU v5e-class pods — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI per chip.  Single pod = 16×16 = 256 chips
-(data × model); multi-pod = 2×16×16 = 512 chips with a leading ``pod``
-axis (DCN-connected in real deployments; the dry-run treats it as a
-mesh axis so the pod-level collective schedule is visible in the HLO).
+Every mesh in the library, the tests and ``chip_smoke.py`` comes from
+:func:`make_mesh`, which asks for ``AxisType.Auto`` axes: the pod
+backend constrains shardings with ``with_sharding_constraint`` and
+scatters into sharded client-state tables, both of which need Auto
+axes (``jax.make_mesh`` defaults to Explicit ones).
 
-``make_production_mesh`` is a function (not a module constant) so that
-importing this module never touches jax device state — the dry-run must
-set XLA_FLAGS before the first jax call.
+Named mesh shapes:
+
+  * :func:`make_host_mesh` — the degenerate 1×1 mesh on the first
+    device (CPU tests, the library's default);
+  * :func:`make_device_mesh` — every device JAX sees, as
+    ``data × model`` (one chip: 1×1; a 2×2 v5e host: 2×2 with
+    ``model=2``);
+  * :func:`make_production_mesh` — the 16×16 single-pod and 2×16×16
+    multi-pod specs the dry-run lowers against.
+
+These are functions (not module constants) so that importing this
+module never touches jax device state — the dry-run must set XLA_FLAGS
+before the first jax call.
+
+``CHIP_PEAKS`` holds the published peaks of each chip the roofline
+reads, keyed by ``device_kind`` as JAX reports it; a device that is not
+in the table is an error, never a default.
 """
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
-# hardware constants used by the roofline (per chip)
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # B/s
-ICI_BW = 50e9                 # B/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops_bf16: float           # FLOP/s
+    hbm_bw: float               # B/s
+    ici_bw: float               # B/s per link
+    source: str
+
+
+# Google Cloud documentation, "TPU v5e" (system architecture): 197 TFLOP/s
+# bf16, 16 GB HBM2 at 819 GB/s, 1,600 Gbit/s inter-chip interconnect over
+# four links (50 GB/s per link).
+V5E = "TPU v5 lite"
+CHIP_PEAKS = {
+    V5E: ChipPeaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9,
+                   source="Google Cloud documentation, TPU v5e"),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind`` (``jax.devices()[0].device_kind``)."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(CHIP_PEAKS)}") from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,25 +79,31 @@ SINGLE_POD = MeshSpec("pod", (16, 16), ("data", "model"))
 MULTI_POD = MeshSpec("multipod", (2, 16, 16), ("pod", "data", "model"))
 
 
-def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and the
-    AxisType enum) only exist on newer jax; every mesh here wants the
-    Auto type, which IS the old default, so fall back silently."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     spec = MULTI_POD if multi_pod else SINGLE_POD
-    return _make_mesh(spec.shape, spec.axes)
+    return make_mesh(spec.shape, spec.axes)
 
 
 def make_host_mesh():
-    """Degenerate 1-device mesh for CPU tests (same axis names)."""
-    return _make_mesh((1, 1), ("data", "model"))
+    """Degenerate 1-device mesh (same axis names)."""
+    return make_mesh((1, 1), ("data", "model"),
+                     devices=jax.devices()[:1])
+
+
+def make_device_mesh(model: int = 1, devices: Optional[Sequence] = None):
+    """``data × model`` mesh over ``devices`` (default: all of
+    ``jax.devices()``), ``model`` devices per tensor-parallel group."""
+    devices = list(jax.devices() if devices is None else devices)
+    if model < 1 or len(devices) % model:
+        raise ValueError(f"model axis {model} does not divide "
+                         f"{len(devices)} devices")
+    return make_mesh((len(devices) // model, model), ("data", "model"),
+                     devices=devices)

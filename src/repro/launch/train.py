@@ -31,7 +31,8 @@ The pre-sampled per-round bodies (``make_pod_cyclic_round`` /
 ``make_pod_fl_round``) are kept for AOT lowering (dry-run HLO analysis)
 and as the per-round-dispatch baseline in benchmarks/perf_pod_round.py.
 
-CLI (CPU, reduced configs):
+CLI (reduced configs by default; ``--full-config`` builds the published
+one; the mesh spans every device JAX sees, on the ``data`` axis):
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
         --rounds 4 --cyclic-rounds 2 --clients 8
 """
@@ -218,6 +219,7 @@ def sample_round_batches(data, ids: np.ndarray, steps: int, batch: int,
 class PodTrainResult:
     params: Pytree
     history: list
+    round_wall_s: list = dataclasses.field(default_factory=list)
 
 
 def run_pod_training(cfg: TransformerConfig, data, *,
@@ -236,7 +238,8 @@ def run_pod_training(cfg: TransformerConfig, data, *,
                      n_pods: Optional[int] = None,
                      store: str = "dense",
                      store_capacity: int = 1024,
-                     overlap: str = "on") -> PodTrainResult:
+                     overlap: str = "on",
+                     init_params: Optional[Pytree] = None) -> PodTrainResult:
     """CyclicFL end-to-end on the pod backend: a declarative P1→P2 phase
     schedule through the shared round engine — no hand-rolled loops.
 
@@ -249,7 +252,9 @@ def run_pod_training(cfg: TransformerConfig, data, *,
     ``eval_fn(params, bx, by) -> (B,)``.  ``eval_every=None`` defaults
     to every round when a custom metric is given (the legacy cadence)
     and to no evaluation otherwise; evaluated rounds carry an ``eval``
-    entry in their history row.
+    entry in their history row.  ``init_params`` continues from a
+    previous run's params (the first phase then skips its seeded init),
+    so running P1 and P2 as two calls equals running them as one.
     """
     from repro.launch.mesh import make_host_mesh
     spec = spec or PodFLSpec()
@@ -289,17 +294,21 @@ def run_pod_training(cfg: TransformerConfig, data, *,
         # aggregate rounds split keys identically — the same seed would
         # replay P1's exact client selections and batch draws in P2.
         # When P2 is the first phase its seed also drives model init,
-        # so only offset when a P1 phase precedes it.
+        # so only offset when a P1 phase (here or in the run that made
+        # init_params) precedes it.
         from repro.fl.pod import HOST_RNG_OFFSET_P2
-        p2_seed = seed + HOST_RNG_OFFSET_P2 if phases else seed
+        p2_seed = seed + HOST_RNG_OFFSET_P2 \
+            if phases or init_params is not None else seed
         phases.append(Phase("P2", PodFLConfig(rounds=fl_rounds, seed=p2_seed,
                                               **common, **fl_extra),
                             eval_fn=eval_fn))
     if not phases:
-        return PodTrainResult(params=init_lm(jax.random.PRNGKey(seed), cfg),
-                              history=[])
+        params = init_params if init_params is not None \
+            else init_lm(jax.random.PRNGKey(seed), cfg)
+        return PodTrainResult(params=params, history=[])
 
-    sched = run_phase_schedule(task, data, phases, verbose=verbose)
+    sched = run_phase_schedule(task, data, phases, verbose=verbose,
+                               init_params=init_params)
     history = []
     for h in sched.history:
         row = {"phase": h["phase"], "round": h["round"],
@@ -307,15 +316,19 @@ def run_pod_training(cfg: TransformerConfig, data, *,
         if "acc" in h:
             row["eval"] = h["acc"]
         history.append(row)
-    return PodTrainResult(params=sched.params, history=history)
+    return PodTrainResult(
+        params=sched.params, history=history,
+        round_wall_s=[w for ph in sched.phases
+                      for w in ph.result.round_wall_s])
 
 
-def main(argv=None) -> int:
-    from repro.configs import get_reduced
-    from repro.data.synthetic import make_synthetic_tokenlm
-
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="tinyllama-1.1b")
+    ap.add_argument("--full-config", action="store_true",
+                    help="build the arch's published config "
+                         "(repro.configs.get_config) in place of its "
+                         "reduced smoke variant")
     ap.add_argument("--rounds", type=int, default=4)
     ap.add_argument("--cyclic-rounds", type=int, default=2)
     ap.add_argument("--clients", "--n-clients", dest="clients", type=int,
@@ -341,9 +354,10 @@ def main(argv=None) -> int:
                     help="step-tail/aggregation implementation: the fused "
                          "flat-first path (default — ShardedFlatView "
                          "buffers preserve the FSDP×TP layout and the "
-                         "kernels run shard-locally; auto-interprets "
-                         "off-TPU) or the per-leaf tree algebra (the "
-                         "parity oracle)")
+                         "kernels run shard-locally; Mosaic on TPU, the "
+                         "Pallas interpreter on any other backend, as "
+                         "printed at start) or the per-leaf tree algebra "
+                         "(the parity oracle)")
     ap.add_argument("--eval-every", type=int, default=0,
                     help="in-program test-accuracy cadence "
                          "(0 = no evaluation; never splits a chunk)")
@@ -408,15 +422,22 @@ def main(argv=None) -> int:
                     help="named trainable-leaf filter (overrides the one "
                          "--peft implies); needs --update-impl fused")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap
 
-    from repro.configs import with_peft
-    cfg = with_peft(get_reduced(args.arch), args.peft)
-    if cfg.input_mode != "tokens":
-        print(f"[train] {args.arch}: pod driver trains token-mode archs; "
-              f"{cfg.input_mode}-mode archs train via the same round fns "
-              "with embedding batches (see examples/)", file=sys.stderr)
-        return 2
+
+def make_config(args) -> TransformerConfig:
+    from repro.configs import get_config, get_reduced, with_peft
+    build = get_config if args.full_config else get_reduced
+    return with_peft(build(args.arch), args.peft)
+
+
+def make_run(args, cfg: TransformerConfig):
+    """``(data, kwargs)`` for ``run_pod_training(cfg, data, **kwargs)``
+    from parsed CLI args: synthetic token data from ``--seed``, the
+    PodFLSpec and the mesh."""
+    from repro.data.synthetic import make_synthetic_tokenlm
+    from repro.launch.mesh import make_device_mesh
+
     data = make_synthetic_tokenlm(
         n_clients=args.clients, seq_len=args.seq, n_seq_per_client=64,
         vocab=cfg.vocab_size, beta=0.5, seed=args.seed)
@@ -433,16 +454,34 @@ def main(argv=None) -> int:
                      secure_agg=args.secure_agg,
                      compression=None if comp.identity else comp,
                      peft=args.peft, trainable_filter=args.trainable_filter)
-    t0 = time.time()
-    res = run_pod_training(
-        cfg, data, cyclic_rounds=args.cyclic_rounds, fl_rounds=args.rounds,
+    return data, dict(
+        cyclic_rounds=args.cyclic_rounds, fl_rounds=args.rounds,
         clients_per_round=args.clients_per_round, spec=spec,
-        seed=args.seed, verbose=True, chunk_size=args.chunk_size,
+        mesh=make_device_mesh(),
+        seed=args.seed, chunk_size=args.chunk_size,
         eval_every=args.eval_every,
         sampling=args.sampling, layout=args.layout,
         aggregation=args.aggregation, n_pods=args.n_pods,
         store=args.store, store_capacity=args.store_capacity,
         overlap=args.overlap)
+
+
+def main(argv=None) -> int:
+    from repro.kernels.ops import describe_fused
+    from repro.launch.compile_cache import enable_compile_cache
+
+    args = build_parser().parse_args(argv)
+    cfg = make_config(args)
+    if cfg.input_mode != "tokens":
+        print(f"[train] {args.arch}: the pod trainer takes token-mode archs; "
+              f"{cfg.input_mode}-mode archs train via the same round fns "
+              "with embedding batches (see examples/)", file=sys.stderr)
+        return 2
+    enable_compile_cache()
+    print(f"[train] {describe_fused(args.update_impl)}", flush=True)
+    data, run_kwargs = make_run(args, cfg)
+    t0 = time.time()
+    res = run_pod_training(cfg, data, verbose=True, **run_kwargs)
     first = res.history[0]["loss"]
     last = res.history[-1]["loss"]
     print(f"[train] {args.arch}: loss {first:.4f} -> {last:.4f} "

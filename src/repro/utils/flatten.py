@@ -227,7 +227,8 @@ class FlatView:
         fz = None
         if self.frozen_sizes:
             fz = dict(frozen) if frozen else self.frozen_zeros()
-        K = next(iter(bufs.values())).shape[0]
+        # an empty tree has no buffers; its K is never read
+        K = next((b.shape[0] for b in bufs.values()), 0)
         leaves = []
         for s in self.slots:
             if is_frozen_bucket(s.buffer):

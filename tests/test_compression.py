@@ -456,7 +456,8 @@ _COMPRESS_SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.fl.pod import PodAggregateStrategy, ShardedSparseClientStateStore
     from repro.fl.task import vision_task
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     task = vision_task("mlp", in_ch=1, seed_kwargs={"img": 8, "d_hidden": 16})
     rng = np.random.default_rng(0)
     N, per = 8, 16

@@ -212,12 +212,12 @@ def test_sharded_rows_are_the_device_tiles():
 def test_sharded_roundtrip_under_named_sharding():
     """device_put with the bucket shardings (n_shards axis over the
     group's axes) then unflatten reproduces the tree exactly."""
-    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import NamedSharding
+    from repro.launch.mesh import make_mesh
     from repro.sharding.rules import flat_buffer_pspec
 
     n = jax.device_count()
-    mesh = Mesh(np.array(jax.devices()).reshape(1, 1, n),
-                ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, n), ("pod", "data", "model"))
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     view = ShardedFlatView.of(SHARDED_TREE, SHARDED_PSPECS, sizes)
     bufs = view.flatten(SHARDED_TREE)

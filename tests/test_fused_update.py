@@ -166,6 +166,46 @@ def test_relay_parity(vision_setup):
     _assert_tree_close(tree.params, fused.params, 2e-5)
 
 
+@pytest.mark.parametrize("case", sorted(
+    __import__("repro.kernels.parity", fromlist=["CASES"]).CASES))
+def test_kernel_matches_its_oracle(case):
+    """Every fused kernel (interpret mode) against its jnp oracle in
+    repro.kernels.ref, on an unaligned length that exercises the
+    wrappers' pad/strip — the same cases chip_smoke.py runs with Mosaic
+    at qwen1.5-0.5b width."""
+    from repro.kernels import parity
+    worst = parity.CASES[case](10_000, jax.random.PRNGKey(3), True)
+    assert worst <= 1.0, f"{case}: {worst} x tolerance"
+
+
+def test_kernel_oracle_check_catches_a_wrong_kernel():
+    """The parity score is not vacuous: a kernel run with the wrong
+    momentum scores far outside tolerance."""
+    from repro.kernels import ops, parity, ref
+    n = 4096
+    p, g, m = parity._operands(jax.random.PRNGKey(0), n, jnp.bfloat16,
+                               jnp.bfloat16, jnp.float32)
+    got = ops.fused_local_step(p, g, m, None, 1.0, 0.1, momentum=0.8,
+                               interpret=True)
+    worst = parity._score(got, lambda *a: ref.local_step_ref(
+        *a, momentum=0.9), p, g, m, None, 1.0, 0.1)
+    assert worst > 10.0
+
+
+@pytest.mark.parametrize("n", [1, 1000, 1024, 65_536, 65_537, 463_987_712])
+def test_padded_len_tiles_both_grids(n):
+    """A carried buffer of padded_len(n) needs no pad on the one-block
+    interpret grid nor on the 512-row compiled grid, and the pad stays
+    below one compiled block."""
+    from repro.kernels.fused_update import (
+        DEFAULT_BLOCK_ROWS, LANES, _grid_rows, padded_len)
+    m = padded_len(n)
+    assert n <= m < n + DEFAULT_BLOCK_ROWS * LANES
+    for interpret in (True, False):
+        rows_p, _ = _grid_rows(m, DEFAULT_BLOCK_ROWS, interpret)
+        assert rows_p * LANES == m
+
+
 def test_bad_update_impl_rejected():
     with pytest.raises(ValueError, match="update_impl"):
         LocalSpec(n_steps=1, batch_size=1, lr=0.1, update_impl="magic")
@@ -307,7 +347,8 @@ _SHARDED_PARITY_SCRIPT = textwrap.dedent("""
     from repro.fl.simulation import HOST_RNG_OFFSET_P2
     from repro.fl.task import lm_task
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     cfg = get_reduced("qwen1.5-0.5b")
     task = lm_task(cfg)
     data = make_synthetic_tokenlm(n_clients=8, seq_len=16,

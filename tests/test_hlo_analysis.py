@@ -9,6 +9,7 @@ from repro.launch.hlo_analysis import (
     RooflineTerms, _shape_bytes, collective_bytes_by_op,
     total_collective_bytes,
 )
+from repro.launch.mesh import V5E
 
 HLO = """
 HloModule jit_step
@@ -50,6 +51,7 @@ def test_collective_bytes_by_op():
 
 def test_roofline_terms_bottleneck():
     t = RooflineTerms(arch="a", shape="s", mesh="m", n_chips=256,
+                      device_kind=V5E,
                       hlo_flops=197e12,          # exactly 1s of compute
                       hlo_bytes=819e9 * 2,       # 2s of memory
                       collective_bytes=int(50e9 * 3),  # 3s of collective
@@ -61,3 +63,16 @@ def test_roofline_terms_bottleneck():
     np.testing.assert_allclose(t.useful_flops_ratio, 1.0)
     d = t.to_dict()
     assert d["bottleneck"] == "collective"
+
+
+def test_chip_peaks_unknown_device_is_an_error():
+    import pytest
+    from repro.launch.mesh import chip_peaks
+    assert chip_peaks(V5E).flops_bf16 == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")
+    t = RooflineTerms(arch="a", shape="s", mesh="m", n_chips=1,
+                      device_kind="cpu", hlo_flops=1.0, hlo_bytes=1.0,
+                      collective_bytes=0, collective_detail={})
+    with pytest.raises(ValueError, match="no published peaks"):
+        t.t_compute

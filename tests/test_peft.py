@@ -306,7 +306,8 @@ _PEFT_SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.sharding import rules
     from repro.utils.flatten import is_frozen_bucket
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     cfg = with_peft(get_reduced("qwen1.5-0.5b"), "lora:4")
     task = lm_task(cfg)
     data = make_synthetic_tokenlm(n_clients=8, seq_len=16,

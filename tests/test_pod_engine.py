@@ -368,7 +368,8 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.fl.pod import PodAggregateStrategy
     from repro.fl.task import lm_task
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     cfg = get_reduced("qwen1.5-0.5b")
     task = lm_task(cfg)
     data = make_synthetic_tokenlm(n_clients=8, seq_len=16,
@@ -423,7 +424,8 @@ _HIER_SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.fl.pod import PodAggregateStrategy, ShardedSparseClientStateStore
     from repro.fl.task import lm_task
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     cfg = get_reduced("qwen1.5-0.5b")
     task = lm_task(cfg)
     data = make_synthetic_tokenlm(n_clients=8, seq_len=16,
@@ -493,7 +495,8 @@ _PSUM_SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.fl.task import vision_task
     from repro.sharding import rules
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     task = vision_task("mlp", in_ch=1, seed_kwargs={"img": 8, "d_hidden": 16})
     fops = _sharded_flat_ops(task, mesh, "fsdp_tp", True)
     G = fops.lane_count()

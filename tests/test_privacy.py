@@ -33,6 +33,7 @@ from repro.fl.pod import PodAggregateStrategy
 from repro.fl.privacy import DPSpec
 from repro.fl.simulation import FLConfig, run_federated
 from repro.fl.task import vision_task
+from repro.launch.mesh import make_host_mesh
 from repro.utils.flatten import FlatView
 
 SEED = 0
@@ -233,7 +234,7 @@ def _host_run(task, data, rounds, **spec_kw):
 
 def test_identity_dpspec_bitwise_pod_fused(vision_setup):
     task, data = vision_setup
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     base = _pod_run(task, data, mesh, 2)
     ident = _pod_run(task, data, mesh, 2, dp=DPSpec(float("inf"), 0.0))
     for a, b in zip(jax.tree_util.tree_leaves(base.params),
@@ -250,7 +251,7 @@ def test_dp_round_host_pod_parity(vision_setup, spec_kw):
     # one round: host vmap aggregate and pod scan draw IDENTICAL noise
     # bits from the same round key, so they match to reduction-order fp
     task, data = vision_setup
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     host = _host_run(task, data, 1, **spec_kw)
     pod = _pod_run(task, data, mesh, 1, **spec_kw)
     for a, b in zip(jax.tree_util.tree_leaves(host.params),

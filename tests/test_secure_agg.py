@@ -26,6 +26,7 @@ from repro.fl.local import FlatParamOps, LocalSpec
 from repro.fl.pod import PodAggregateStrategy
 from repro.fl.simulation import FLConfig, run_federated
 from repro.fl.task import vision_task
+from repro.launch.mesh import make_host_mesh
 from repro.utils.flatten import FlatView
 
 SEED = 0
@@ -175,7 +176,7 @@ def test_masked_run_matches_unmasked_host(vision_setup, update_impl):
 
 def test_masked_run_matches_unmasked_pod(vision_setup):
     task, data = vision_setup
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
 
     def run(secure_agg):
         strat = PodAggregateStrategy(
@@ -227,7 +228,8 @@ _MASK_SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.fl.privacy import DPSpec
     from repro.fl.task import vision_task
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     task = vision_task("mlp", in_ch=1, seed_kwargs={"img": 8, "d_hidden": 16})
     rng = np.random.default_rng(0)
     N, per = 8, 16

@@ -49,7 +49,8 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.models.transformer import init_lm
     from repro.sharding import rules
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     cfg = get_reduced("tinyllama-1.1b")
     params = jax.eval_shape(lambda k: init_lm(k, cfg), jax.random.PRNGKey(0))
 
