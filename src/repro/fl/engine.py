@@ -131,7 +131,6 @@ from __future__ import annotations
 import concurrent.futures
 import dataclasses
 import functools
-import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -150,6 +149,7 @@ from repro.fl.local import (
 from repro.fl.task import Task
 from repro.kernels import ops
 from repro.utils import tree_math as tm
+from repro.utils.spans import scoped, span
 
 Pytree = Any
 
@@ -315,11 +315,8 @@ class _SpillBlock:
             self._future = _spill_pool().submit(self._materialize, meta)
 
     def _materialize(self, meta: Optional[dict]):
-        t0 = time.perf_counter()
-        out = [np.asarray(leaf) for leaf in self.rows]
-        if meta is not None:
-            meta["spill_ms"] = meta.get("spill_ms", 0.0) + \
-                (time.perf_counter() - t0) * 1e3
+        with span("store.spill", meta, "spill_ms"):
+            out = [np.asarray(leaf) for leaf in self.rows]
         self._np = out
         self.rows = None                # drop the device handles
         return out
@@ -507,13 +504,13 @@ class SparseClientStateStore:
             tmpl = self._meta["template"]
             fill = [self._pop_cold(int(cid)) or tmpl for cid in miss]
             rows_np = self._stage_rows(fill)
-            t0 = time.perf_counter()
-            placement = self._refill_placement(victims)
-            rows_dev = [jax.device_put(r) if placement is None
-                        else jax.device_put(r, s)
-                        for r, s in zip(rows_np, _broadcast(placement,
-                                                            len(rows_np)))]
-            self._meta["transfer_ms"] += (time.perf_counter() - t0) * 1e3
+            with span("store.transfer", self._meta, "transfer_ms"):
+                placement = self._refill_placement(victims)
+                rows_dev = [jax.device_put(r) if placement is None
+                            else jax.device_put(r, s)
+                            for r, s in zip(rows_np,
+                                            _broadcast(placement,
+                                                       len(rows_np)))]
             gone = evicted[evicted >= 0]
             slot_of[gone] = -1
             slot_of[miss] = victims
@@ -1002,6 +999,7 @@ class AggregateStrategy(HostBackend):
                     lambda rk, ids, p, wl, w: fused_aggregate(fops, p, wl, w))
             unpack = fops.unflatten
             stacked_unpack = fops.stacked_unflatten
+        aggregate = scoped("fl_aggregate", aggregate)
 
         def body(key, params, x_all, y_all, ids, weights, lr_scale, algo_state,
                  frozen=None):
@@ -1238,17 +1236,32 @@ class EngineResult:
     algo_state: Dict[str, Pytree]
     server_state: Any = None
     dispatches: int = 0             # chunk-program invocations this run
-    # wall-time breakdown of the chunk loop (totals over the run, ms):
-    # host_residency_ms = stage planning + staging-transfer enqueue,
-    # staged_transfer_ms = the device_put slice of that (store-reported),
-    # dispatch_enqueue_ms = commit + chunk_fn call overhead,
-    # device_wait_ms = blocking on the dispatched chunk's outputs,
+    # wall-time breakdown of the run (totals, ms), each summed from the
+    # ``engine.*`` / ``store.*`` spans named beside it:
+    # host_residency_ms = stage planning + staging-transfer enqueue
+    #   (engine.stage),
+    # staged_transfer_ms = the device_put slice of that (store.transfer),
+    # dispatch_enqueue_ms = commit + chunk_fn call overhead
+    #   (engine.dispatch),
+    # device_wait_ms = blocking on the dispatched chunk's outputs
+    #   (engine.drain),
     # spill_materialize_ms = background spill→numpy conversion time
-    # (work moved OFF the critical path, not added to it)
+    #   (store.spill: work moved OFF the critical path, not added to it),
+    # pack_ms = flatten + place of the params, algorithm and server
+    #   state init (engine.pack),
+    # prepare_data_ms = the data and eval stream's upload
+    #   (engine.prepare_data),
+    # unpack_ms = the flat carries back to trees (engine.unpack)
     timing: Optional[Dict[str, float]] = None
-    # per history row: host clock from its dispatch to drained losses
-    # (compilation included), split evenly over the chunk's rounds
+    # per history row: host clock from its dispatch to drained losses,
+    # split evenly over the chunk's rounds.  It includes the chunk
+    # program's compile on the first call of a shape, and leaves out the
+    # host time between dispatches (planning, history, packing and
+    # unpacking): not a rate of the whole run
     round_wall_s: List[float] = dataclasses.field(default_factory=list)
+    # JAX traces and backend compiles charged to the innermost engine
+    # span open when they happened: {"traces.engine.unpack": 57, ...}
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 def make_chunk_fn(task: Task, strategy, schedule: RoundSchedule,
@@ -1332,12 +1345,15 @@ def _cached_chunk_fn(task: Task, strategy, sampling: str,
                 rk, params, x_all, y_all, ids_r, weights, lr_scale, algo_state,
                 frozen)
             if server is not None:
-                new_params, server_state = server[1](params, new_params,
-                                                     server_state)
+                with jax.named_scope("fl_server_update"):
+                    new_params, server_state = server[1](params, new_params,
+                                                         server_state)
             m = None
             if metric is not None:
-                m = jax.lax.cond(do_eval, evaluate,
-                                 lambda _: jnp.float32(jnp.nan), new_params)
+                with jax.named_scope("fl_eval"):
+                    m = jax.lax.cond(do_eval, evaluate,
+                                     lambda _: jnp.float32(jnp.nan),
+                                     new_params)
             return (key, new_params, algo_state, server_state), (loss, m)
 
         (key, params, algo_state, server_state), (losses, metrics) = \
@@ -1387,8 +1403,16 @@ def run_rounds(task: Task, data: FederatedDataset, strategy,
     ``eval_fn(params, bx, by) -> (B,)``; the history rows record the
     stream's weighted mean under the ``"acc"`` key either way.
     """
+    # every host stretch below is an ``engine.*`` span (repro.utils.spans):
+    # on the profiler's clock, summed into ``timing``, and charged the
+    # JAX traces and compiles that happen inside it (``counters``)
+    timing = {"host_residency_ms": 0.0, "staged_transfer_ms": 0.0,
+              "dispatch_enqueue_ms": 0.0, "device_wait_ms": 0.0,
+              "spill_materialize_ms": 0.0, "pack_ms": 0.0,
+              "prepare_data_ms": 0.0, "unpack_ms": 0.0}
+    counters: Dict[str, int] = {}
     key = jax.random.PRNGKey(schedule.seed)
-    params = init_params if init_params is not None else task.init(key)
+    n_clients = data.n_clients
     # flat-first: on the fused path the engine's working params are the
     # strategy's flat buffers from here to the EngineResult — the server
     # OptState inits flat too, and trees reappear only at the eval /
@@ -1400,36 +1424,40 @@ def run_rounds(task: Task, data: FederatedDataset, strategy,
     # placement would be dead work.
     fops = strategy.flat_ops(task)
     frozen: Dict[str, jnp.ndarray] = {}
-    if fops is None:
-        # backend hook: copy (host) or device_put with shardings (pod) so
-        # the donated carries never invalidate the caller's init_params
-        params = strategy.place_params(params)
-    else:
-        # pack + place FIRST: init_state sees the engine's working
-        # representation, so per-client state initializes flat too.
-        # Frozen leaves pack ONCE per phase into the read-only constant
-        # bucket ({} for an unfiltered view): non-donated, outside the
-        # chunk carry, merged back only at tree boundaries.
-        frozen = fops.place_frozen(fops.flatten_frozen(params))
-        params = fops.place(fops.flatten(params))
-
-    n_clients = data.n_clients
+    with span("engine.pack", timing, "pack_ms", counters):
+        params = init_params if init_params is not None else task.init(key)
+        if fops is None:
+            # backend hook: copy (host) or device_put with shardings (pod)
+            # so the donated carries never invalidate the caller's
+            # init_params
+            params = strategy.place_params(params)
+        else:
+            # pack + place FIRST: init_state sees the engine's working
+            # representation, so per-client state initializes flat too.
+            # Frozen leaves pack ONCE per phase into the read-only
+            # constant bucket ({} for an unfiltered view): non-donated,
+            # outside the chunk carry, merged back only at tree
+            # boundaries.
+            frozen = fops.place_frozen(fops.flatten_frozen(params))
+            params = fops.place(fops.flatten(params))
+        algo_state = strategy.init_state(task, params, n_clients)
+        server = strategy.make_server_update(task)
+        server_state = server[0](params) if server is not None else ()
+        server_state = strategy.place_server_state(server_state, task)
     K = strategy.n_selected(n_clients)
-    algo_state = strategy.init_state(task, params, n_clients)
-    server = strategy.make_server_update(task)
-    server_state = server[0](params) if server is not None else ()
-    server_state = strategy.place_server_state(server_state, task)
 
     with_eval = schedule.eval_every > 0 and len(np.asarray(data.test_y)) > 0
     metric = None
     if with_eval:
         metric = eval_fn if eval_fn is not None else make_accuracy_metric(task)
     chunk_fn = make_chunk_fn(task, strategy, schedule, n_clients, metric)
-    x_all, y_all, n_real = strategy.prepare_data(data)
     ev_x = ev_y = ev_w = None
-    if with_eval:
-        ev_x, ev_y, ev_w = strategy.prepare_eval_data(
-            batch_test_set(data.test_x, data.test_y, schedule.eval_batch))
+    with span("engine.prepare_data", timing, "prepare_data_ms", counters):
+        x_all, y_all, n_real = strategy.prepare_data(data)
+        if with_eval:
+            ev_x, ev_y, ev_w = strategy.prepare_eval_data(
+                batch_test_set(data.test_x, data.test_y,
+                               schedule.eval_batch))
 
     host_rng = None
     if schedule.sampling == "host":
@@ -1457,10 +1485,6 @@ def run_rounds(task: Task, data: FederatedDataset, strategy,
     # before chunk N's carried key has materialized
     replay_key = key
 
-    timing = {"host_residency_ms": 0.0, "staged_transfer_ms": 0.0,
-              "dispatch_enqueue_ms": 0.0, "device_wait_ms": 0.0,
-              "spill_materialize_ms": 0.0}
-
     def stores_ms(attr: str) -> float:
         return sum(float(getattr(s, attr, 0.0) or 0.0) for s in stores)
 
@@ -1474,43 +1498,48 @@ def run_rounds(task: Task, data: FederatedDataset, strategy,
         round index, so planning order == execution order keeps the
         streams bit-identical whether or not the loop overlaps."""
         nonlocal replay_key
-        R = min(chunk, schedule.rounds - rnd)
-        ids = None
-        if host_rng is not None:
-            ids = jnp.asarray(np.stack([
-                host_rng.choice(n_clients, size=K, replace=False)
-                for _ in range(R)]))
-        ids_block = None
-        if sparse_residency:
-            # host sampling: the ids are already known; device sampling:
-            # replay the chunk's in-program draw (bit-identical threefry
-            # recurrence) — residency only, the program still samples
-            # in-program unchanged
-            if ids is not None:
-                ids_block = np.asarray(ids)
-            else:
-                ids_block, replay_key = _replay_device_sampling(
-                    replay_key, n_clients, K, R)
-        lr_scales = jnp.asarray(
-            [schedule.lr_decay ** (rnd + j) for j in range(R)], jnp.float32)
-        # the eval cadence is a host-computed mask over GLOBAL round
-        # indices, so it is independent of how rounds chunk into dispatches
-        eval_mask = None
-        do_eval = [False] * R
-        if with_eval:
-            do_eval = [(rnd + j + 1) % schedule.eval_every == 0
-                       or rnd + j + 1 == schedule.rounds for j in range(R)]
-            eval_mask = jnp.asarray(do_eval)
-        return _ChunkPlan(rnd=rnd, R=R, ids=ids, ids_block=ids_block,
-                          lr_scales=lr_scales, eval_mask=eval_mask,
-                          do_eval=do_eval)
+        with span("engine.plan", counts=counters, round=rnd):
+            R = min(chunk, schedule.rounds - rnd)
+            ids = None
+            if host_rng is not None:
+                ids = jnp.asarray(np.stack([
+                    host_rng.choice(n_clients, size=K, replace=False)
+                    for _ in range(R)]))
+            ids_block = None
+            if sparse_residency:
+                # host sampling: the ids are already known; device
+                # sampling: replay the chunk's in-program draw
+                # (bit-identical threefry recurrence) — residency only,
+                # the program still samples in-program unchanged
+                if ids is not None:
+                    ids_block = np.asarray(ids)
+                else:
+                    ids_block, replay_key = _replay_device_sampling(
+                        replay_key, n_clients, K, R)
+            lr_scales = jnp.asarray(
+                [schedule.lr_decay ** (rnd + j) for j in range(R)],
+                jnp.float32)
+            # the eval cadence is a host-computed mask over GLOBAL round
+            # indices, so it is independent of how rounds chunk into
+            # dispatches
+            eval_mask = None
+            do_eval = [False] * R
+            if with_eval:
+                do_eval = [(rnd + j + 1) % schedule.eval_every == 0
+                           or rnd + j + 1 == schedule.rounds
+                           for j in range(R)]
+                eval_mask = jnp.asarray(do_eval)
+            return _ChunkPlan(rnd=rnd, R=R, ids=ids, ids_block=ids_block,
+                              lr_scales=lr_scales, eval_mask=eval_mask,
+                              do_eval=do_eval)
 
     def stage(plan: _ChunkPlan) -> None:
         if plan.ids_block is None:
             return
-        t0 = time.perf_counter()
-        plan.staged = strategy.stage_chunk_state(plan.ids_block.reshape(-1))
-        timing["host_residency_ms"] += (time.perf_counter() - t0) * 1e3
+        with span("engine.stage", timing, "host_residency_ms", counters,
+                  round=plan.rnd):
+            plan.staged = strategy.stage_chunk_state(
+                plan.ids_block.reshape(-1))
 
     history: List[Dict[str, float]] = []
     round_wall_s: List[float] = []
@@ -1520,14 +1549,15 @@ def run_rounds(task: Task, data: FederatedDataset, strategy,
     while plan is not None:
         if staged_plan is not plan:     # sync path (or the first chunk)
             stage(plan)
-        t_dispatch = t0 = time.perf_counter()
-        algo_state = strategy.commit_chunk_state(algo_state, plan.staged)
-        key, params, algo_state, server_state, losses, metrics = chunk_fn(
-            key, params, algo_state, server_state, x_all, y_all, n_real,
-            plan.ids, plan.lr_scales, plan.eval_mask, ev_x, ev_y, ev_w,
-            frozen)
+        rnd, R = plan.rnd, plan.R
+        with span("engine.dispatch", timing, "dispatch_enqueue_ms", counters,
+                  round=rnd) as sent:
+            algo_state = strategy.commit_chunk_state(algo_state, plan.staged)
+            key, params, algo_state, server_state, losses, metrics = \
+                chunk_fn(key, params, algo_state, server_state, x_all,
+                         y_all, n_real, plan.ids, plan.lr_scales,
+                         plan.eval_mask, ev_x, ev_y, ev_w, frozen)
         dispatches += 1
-        timing["dispatch_enqueue_ms"] += (time.perf_counter() - t0) * 1e3
 
         nxt = None
         if overlap and plan.rnd + plan.R < schedule.rounds:
@@ -1536,26 +1566,26 @@ def run_rounds(task: Task, data: FederatedDataset, strategy,
             stage(nxt)
             staged_plan = nxt
 
-        t0 = time.perf_counter()
-        losses = np.asarray(losses)     # blocks: the dispatch drains here
-        t_done = time.perf_counter()
-        timing["device_wait_ms"] += (t_done - t0) * 1e3
-        metrics = np.asarray(metrics) if metrics is not None else None
+        with span("engine.drain", timing, "device_wait_ms", counters,
+                  round=rnd) as drained:
+            losses = np.asarray(losses)  # blocks: the dispatch drains here
 
-        rnd, R = plan.rnd, plan.R
-        for j in range(R):
-            if ledger is not None:
-                strategy.record(ledger, K, params, task)
-            row = {"round": rnd + j, "local_loss": float(losses[j]),
-                   "phase": phase}
-            round_wall_s.append((t_done - t_dispatch) / R)
-            if plan.do_eval[j]:
-                row["acc"] = float(metrics[j])
-                if verbose:
-                    print(f"[{label}] round {rnd + j + 1}/{schedule.rounds} "
-                          f"loss={row['local_loss']:.4f} acc={row['acc']:.4f}",
-                          flush=True)
-            history.append(row)
+        with span("engine.history", counts=counters, round=rnd):
+            metrics = np.asarray(metrics) if metrics is not None else None
+            for j in range(R):
+                if ledger is not None:
+                    strategy.record(ledger, K, params, task)
+                row = {"round": rnd + j, "local_loss": float(losses[j]),
+                       "phase": phase}
+                round_wall_s.append((drained.t1 - sent.t0) / R)
+                if plan.do_eval[j]:
+                    row["acc"] = float(metrics[j])
+                    if verbose:
+                        print(f"[{label}] round {rnd + j + 1}/"
+                              f"{schedule.rounds} "
+                              f"loss={row['local_loss']:.4f} "
+                              f"acc={row['acc']:.4f}", flush=True)
+                history.append(row)
 
         if switch_policy is not None and switch_policy.should_switch(
                 rnd + R - 1, history):
@@ -1572,12 +1602,13 @@ def run_rounds(task: Task, data: FederatedDataset, strategy,
         - spill_ms0
 
     if fops is not None:                # EngineResult speaks trees
-        params = fops.unflatten(params, frozen)
-        server_state = unpack_server_state(fops, server_state)
+        with span("engine.unpack", timing, "unpack_ms", counters):
+            params = fops.unflatten(params, frozen)
+            server_state = unpack_server_state(fops, server_state)
         # algo_state stays in the carried representation (flat row
         # buffers / sparse store tables) — materializing an
         # (n_clients, model) tree here would defeat the sparse store
     return EngineResult(params=params, history=history,
                         algo_state=algo_state, server_state=server_state,
                         dispatches=dispatches, timing=timing,
-                        round_wall_s=round_wall_s)
+                        round_wall_s=round_wall_s, counters=counters)

@@ -590,9 +590,12 @@ def make_local_fn(task: Task, spec: LocalSpec,
         def step(carry, step_key):
             params, mom = carry
             bidx = jax.random.randint(step_key, (spec.batch_size,), 0, n_data)
-            loss, grads = grad_fn(params, extras, cx[bidx], cy[bidx], step_key)
-            params, mom = tree_step_tail(spec, params, grads, mom, c_diff,
-                                         lr_scale)
+            with jax.named_scope("fl_fwd_bwd"):
+                loss, grads = grad_fn(params, extras, cx[bidx], cy[bidx],
+                                      step_key)
+            with jax.named_scope("fl_step_tail"):
+                params, mom = tree_step_tail(spec, params, grads, mom, c_diff,
+                                             lr_scale)
             return (params, mom), loss
 
         keys = jax.random.split(key, spec.n_steps)
@@ -616,17 +619,6 @@ def make_local_fn(task: Task, spec: LocalSpec,
         else:
             c_bufs = flat_ops.pad(flat_ops.flatten(extras["c_diff"]))
 
-        # differentiate w.r.t. the FLAT buffers: the tree materializes
-        # only here, inside the loss closure, so the backward's
-        # cotangents land directly in packed buffer form — the per-step
-        # pack copy of the PR-4 flow does not exist.  ``frozen`` enters
-        # as a closed-over constant on the non-differentiated side, so
-        # the backward never touches (or allocates cotangents for) the
-        # frozen leaves.
-        def flat_loss(p_bufs, bx, by, rng):
-            return loss_for_variant(flat_ops.unflatten(p_bufs, frozen),
-                                    extras, bx, by, rng)
-
         # the scan carries padded 1-D (host) buffers as (rows, 128)
         # tiles, the kernels' own shape: vmapped over clients, a (K, n)
         # carry is laid out on TPU in (K, 128) tiles, and every
@@ -642,17 +634,31 @@ def make_local_fn(task: Task, spec: LocalSpec,
         def untile(bufs):
             return {k: b.reshape(shapes[k]) for k, b in bufs.items()}
 
-        grad_fn = jax.value_and_grad(
-            lambda p_tiles, *a: flat_loss(untile(p_tiles), *a))
+        # differentiate w.r.t. the FLAT buffers: the tree materializes
+        # only here, inside the loss closure, so the backward's
+        # cotangents land directly in packed buffer form — the per-step
+        # pack copy of the PR-4 flow does not exist.  ``frozen`` enters
+        # as a closed-over constant on the non-differentiated side, so
+        # the backward never touches (or allocates cotangents for) the
+        # frozen leaves.
+        def flat_loss(p_tiles, bx, by, rng):
+            with jax.named_scope("fl_unflatten"):
+                params = flat_ops.unflatten(untile(p_tiles), frozen)
+            return loss_for_variant(params, extras, bx, by, rng)
+
+        grad_fn = jax.value_and_grad(flat_loss)
 
         def step(carry, step_key):
             p_bufs, m_bufs = carry
             bidx = jax.random.randint(step_key, (spec.batch_size,), 0, n_data)
-            loss, g_bufs = grad_fn(p_bufs, cx[bidx], cy[bidx], step_key)
-            p_bufs, m_bufs = fused_step_tail(
-                spec, flat_ops, untile(p_bufs), untile(g_bufs),
-                untile(m_bufs), c_bufs, lr_scale)
-            return (tile(p_bufs), tile(m_bufs)), loss
+            with jax.named_scope("fl_fwd_bwd"):
+                loss, g_bufs = grad_fn(p_bufs, cx[bidx], cy[bidx], step_key)
+            with jax.named_scope("fl_step_tail"):
+                p_bufs, m_bufs = fused_step_tail(
+                    spec, flat_ops, untile(p_bufs), untile(g_bufs),
+                    untile(m_bufs), c_bufs, lr_scale)
+                p_bufs, m_bufs = tile(p_bufs), tile(m_bufs)
+            return (p_bufs, m_bufs), loss
 
         keys = jax.random.split(key, spec.n_steps)
         (p_end, _), losses = jax.lax.scan(step, (tile(p_start), tile(m0)),

@@ -112,6 +112,7 @@ from repro.kernels import ops
 from repro.kernels.fused_update import LANES, padded_len
 from repro.sharding import rules
 from repro.utils import tree_math as tm
+from repro.utils.spans import scoped
 
 Pytree = Any
 
@@ -587,15 +588,19 @@ def _flatten_on_mesh(fops: "ShardedFlatOps", tree: Pytree,
 _pack_on_mesh = jax.jit(_flatten_on_mesh, static_argnums=(0, 2))
 
 
+# the unpack and its transpose both read as the ``fl_unflatten`` phase
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+@functools.partial(scoped, "fl_unflatten")
 def _mesh_unflatten(fops, bufs, frozen):
     return _unflatten_on_mesh(fops, bufs, frozen)
 
 
+@functools.partial(scoped, "fl_unflatten")
 def _mesh_unflatten_fwd(fops, bufs, frozen):
     return _unflatten_on_mesh(fops, bufs, frozen), None
 
 
+@functools.partial(scoped, "fl_unflatten")
 def _mesh_unflatten_bwd(fops, _, ct):
     return _flatten_on_mesh(fops, ct), None       # frozen: no gradient
 
@@ -968,6 +973,15 @@ class PodAggregateStrategy(PodBackendMixin, AggregateStrategy):
                     scale = privacy.clip_scale(dp, sqnorm(w_end, params))
                     return base_add(delta, w_end, w_i * scale)
 
+            # every step of the aggregation reads as the fl_aggregate phase
+            zeros_delta, add_delta, apply_delta = (
+                scoped("fl_aggregate", f)
+                for f in (zeros_delta, add_delta, apply_delta))
+            if fused:
+                compress_client = scoped("fl_aggregate", compress_client)
+                lane_accum = scoped("fl_aggregate", fops.lane_accum)
+                lane_combine = scoped("fl_aggregate", fops.lane_combine)
+
             # -- per-algorithm client step -------------------------------
             # client(k, cxi, cyi, row) -> (w_end, out, loss): ``row`` is
             # this client's state-store row (() when stateless), ``out``
@@ -1070,19 +1084,18 @@ class PodAggregateStrategy(PodBackendMixin, AggregateStrategy):
                             w_end_g, out_g, loss_g = vclient(k_g, cx_g,
                                                              cy_g, row_g)
                             c_g, r_new_g = vcompress(w_end_g, r_g)
-                            return (fops.lane_accum(delta_g, c_g,
-                                                    w_g / wsum),
+                            return (lane_accum(delta_g, c_g, w_g / wsum),
                                     (out_g, loss_g, r_new_g))
 
                         delta_g, (outs, losses, r_outs) = jax.lax.scan(
                             one_step, fops.lane_zeros(G),
                             resh((keys, cx, cy, w32, rows, ef_rows)))
-                        delta = fops.lane_combine(delta_g)
+                        delta = lane_combine(delta_g)
                         delta = jax.lax.with_sharding_constraint(delta,
                                                                  p_sh)
                     else:
-                        vadd = jax.vmap(
-                            lambda a, c, w: fops.delta_accum(a, c, None, w))
+                        vadd = scoped("fl_aggregate", jax.vmap(
+                            lambda a, c, w: fops.delta_accum(a, c, None, w)))
                         delta0 = jax.tree_util.tree_map(
                             lambda d: jnp.zeros((G,) + d.shape, d.dtype),
                             zeros_delta())
@@ -1098,8 +1111,9 @@ class PodAggregateStrategy(PodBackendMixin, AggregateStrategy):
                         delta_g, (outs, losses, r_outs) = jax.lax.scan(
                             one_step, delta0,
                             resh((keys, cx, cy, w32, rows, ef_rows)))
-                        delta = jax.tree_util.tree_map(
-                            lambda d: jnp.sum(d, axis=0), delta_g)
+                        with jax.named_scope("fl_aggregate"):
+                            delta = jax.tree_util.tree_map(
+                                lambda d: jnp.sum(d, axis=0), delta_g)
                 elif lane_psum and dp_clips:
                     # clipped coefficients no longer sum to 1, so the
                     # −(Σc)·p term cannot factor out as −p: carry the
@@ -1115,37 +1129,38 @@ class PodAggregateStrategy(PodBackendMixin, AggregateStrategy):
                         w_end_g, out_g, loss_g = vclient(k_g, cx_g, cy_g,
                                                          row_g)
                         coeffs = (w_g / wsum) * dp_scales(w_end_g)
-                        return ((fops.lane_accum(delta_g, w_end_g, coeffs),
+                        return ((lane_accum(delta_g, w_end_g, coeffs),
                                  csum + jnp.sum(coeffs)),
                                 (out_g, loss_g))
 
                     (delta_g, csum), (outs, losses) = jax.lax.scan(
                         one_step, (fops.lane_zeros(G), jnp.float32(0.0)),
                         resh((keys, cx, cy, w32, rows)))
-                    acc = fops.lane_combine(delta_g)
+                    acc = lane_combine(delta_g)
                     acc = jax.lax.with_sharding_constraint(acc, p_sh)
-                    delta = {name: acc[name] -
-                             csum * params[name].astype(jnp.float32)
-                             for name in acc}
+                    with jax.named_scope("fl_aggregate"):
+                        delta = {name: acc[name] -
+                                 csum * params[name].astype(jnp.float32)
+                                 for name in acc}
                 elif lane_psum:
                     def one_step(delta_g, inp):
                         k_g, cx_g, cy_g, w_g, row_g = inp
                         w_end_g, out_g, loss_g = vclient(k_g, cx_g, cy_g,
                                                          row_g)
-                        return (fops.lane_accum(delta_g, w_end_g,
-                                                w_g / wsum),
+                        return (lane_accum(delta_g, w_end_g, w_g / wsum),
                                 (out_g, loss_g))
 
                     delta_g, (outs, losses) = jax.lax.scan(
                         one_step, fops.lane_zeros(G),
                         resh((keys, cx, cy, w32, rows)))
-                    acc = fops.lane_combine(delta_g)
+                    acc = lane_combine(delta_g)
                     acc = jax.lax.with_sharding_constraint(acc, p_sh)
                     # A = Σᵢ cᵢ·wᵢ came back combined; the −(Σc)·p term
                     # factors out exactly (Σᵢ wᵢ/wsum = 1), applied once
-                    delta = {name: acc[name] -
-                             params[name].astype(jnp.float32)
-                             for name in acc}
+                    with jax.named_scope("fl_aggregate"):
+                        delta = {name: acc[name] -
+                                 params[name].astype(jnp.float32)
+                                 for name in acc}
                 else:
                     vadd = jax.vmap(add_delta, in_axes=(0, 0, 0))
                     delta0 = jax.tree_util.tree_map(
@@ -1162,8 +1177,9 @@ class PodAggregateStrategy(PodBackendMixin, AggregateStrategy):
                         one_step, delta0, resh((keys, cx, cy, w32, rows)))
                     # the single cross-pod combine: one reduction per
                     # bucket over the G pod partials
-                    delta = jax.tree_util.tree_map(
-                        lambda d: jnp.sum(d, axis=0), delta_g)
+                    with jax.named_scope("fl_aggregate"):
+                        delta = jax.tree_util.tree_map(
+                            lambda d: jnp.sum(d, axis=0), delta_g)
                 # (S, G, ...) lane outputs fold back to client order —
                 # client j ran as step j//G, lane j%G
                 outs = jax.tree_util.tree_map(
@@ -1173,11 +1189,13 @@ class PodAggregateStrategy(PodBackendMixin, AggregateStrategy):
                     r_outs = jax.tree_util.tree_map(
                         lambda a: a.reshape((K,) + a.shape[2:]), r_outs)
             elif compressed:
+                accum = scoped("fl_aggregate", fops.delta_accum)
+
                 def one_client(delta, inp):
                     k, cxi, cyi, w_i, row, r_row = inp
                     w_end, out, loss = client(k, cxi, cyi, row)
                     c, r_new = compress_client(w_end, r_row)
-                    return (fops.delta_accum(delta, c, None, w_i / wsum),
+                    return (accum(delta, c, None, w_i / wsum),
                             (out, loss, r_new))
 
                 delta, (outs, losses, r_outs) = jax.lax.scan(
@@ -1195,13 +1213,14 @@ class PodAggregateStrategy(PodBackendMixin, AggregateStrategy):
             # aggregated DP noise + secure-agg masks: independent of the
             # client outputs, so computed once per round and added to the
             # f32 delta in every topology (None statically when off)
-            extra = privacy.round_extra(
-                dp, spec.secure_agg, key, ids, w32 / wsum,
-                zeros_fn=zeros_delta,
-                normal_fn=fops.normal if fused else
-                (lambda k: privacy.tree_normal(k, params)))
-            if extra is not None:
-                delta = jax.tree_util.tree_map(jnp.add, delta, extra)
+            with jax.named_scope("fl_aggregate"):
+                extra = privacy.round_extra(
+                    dp, spec.secure_agg, key, ids, w32 / wsum,
+                    zeros_fn=zeros_delta,
+                    normal_fn=fops.normal if fused else
+                    (lambda k: privacy.tree_normal(k, params)))
+                if extra is not None:
+                    delta = jax.tree_util.tree_map(jnp.add, delta, extra)
 
             new_params = pin(apply_delta(params, delta))
 

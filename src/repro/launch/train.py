@@ -39,6 +39,7 @@ one; the mesh spans every device JAX sees, on the ``data`` axis):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import sys
 import time
@@ -422,6 +423,11 @@ def build_parser() -> argparse.ArgumentParser:
                     help="named trainable-leaf filter (overrides the one "
                          "--peft implies); needs --update-impl fused")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--trace-dir", default=None,
+                    help="write a JAX profiler trace of the whole run "
+                         "here: the engine's engine.* host spans and the "
+                         "round program's fl_* phases on one clock "
+                         "(docs/ARCHITECTURE.md, Observability)")
     return ap
 
 
@@ -481,7 +487,10 @@ def main(argv=None) -> int:
     print(f"[train] {describe_fused(args.update_impl)}", flush=True)
     data, run_kwargs = make_run(args, cfg)
     t0 = time.time()
-    res = run_pod_training(cfg, data, verbose=True, **run_kwargs)
+    trace = (jax.profiler.trace(args.trace_dir) if args.trace_dir
+             else contextlib.nullcontext())
+    with trace:
+        res = run_pod_training(cfg, data, verbose=True, **run_kwargs)
     first = res.history[0]["loss"]
     last = res.history[-1]["loss"]
     print(f"[train] {args.arch}: loss {first:.4f} -> {last:.4f} "
