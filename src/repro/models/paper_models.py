@@ -18,11 +18,14 @@ Each model is an (init, apply) pair over dict params; apply signature is
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
+import jax.extend
 import jax.numpy as jnp
+from jax.interpreters import ad, batching, mlir
 
 from repro.models.layers import he_normal, normal_init
 from repro.utils.registry import Registry
@@ -40,11 +43,151 @@ def init_conv(key, k: int, c_in: int, c_out: int, dtype=jnp.float32) -> Pytree:
     return {"w": w, "b": jnp.zeros((c_out,), dtype)}
 
 
+_NHWC = ("NHWC", "HWIO", "NHWC")
+
+# The side of the MXU's tile: a merged kernel gradient stacks at most this
+# many input channels and this many output channels.
+MXU_SIDE = 128
+# Kernels whose gradients merge the clients: C_in below the first, C_out
+# at most the second.  On a v5e at K=10 the merged gradient beats the
+# grouped one 2-5x at (C_in, C_out) = (3, 6), (6, 16) and (1, 16), ties at
+# (3, 16), and loses at (16, 16), (1, 32) and every wider paper conv.
+MERGED_C_IN, MERGED_C_OUT = 16, 16
+# Platforms (``lax.platform_dependent`` names) whose vmapped kernel gradient
+# merges the clients.  Elsewhere the grouped form stays: XLA's CPU backend
+# runs it well, and computes the merged form's thrown-away blocks in full
+# (the CPU tests ran 5x slower with it).
+MERGED_PLATFORMS = ("tpu",)
+
+
 def conv2d(p: Pytree, x: jnp.ndarray, stride: int = 1, padding: str = "SAME") -> jnp.ndarray:
-    y = jax.lax.conv_general_dilated(
-        x, p["w"].astype(x.dtype), (stride, stride), padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    """NHWC convolution plus bias.
+
+    The convolution and its two transposes, the input and the kernel
+    gradient, are one primitive (``paper_conv``), so that the kernel
+    gradient has a batching rule of its own and every form of autodiff
+    (forward, reverse, and either over the other) still goes through
+    it.  Each lowers to the convolution that ``conv_general_dilated``
+    and its autodiff give; one model, outside a ``vmap`` (the P1 relay,
+    evaluation), compiles as before.
+
+    Under the client ``vmap`` each client has its own kernel, and the
+    vmapped kernel gradient is a convolution grouped over the clients
+    whose window is the whole image.  Where both sides of the kernel are
+    narrow (C_in < ``MERGED_C_IN``, C_out <= ``MERGED_C_OUT``) that form
+    runs slowly on the TPU, and the batching rule stacks the channels of ``g``
+    clients instead and runs one ungrouped convolution over them, whose
+    diagonal blocks are the clients' own gradients (``client_groups``,
+    ``_clients_kernel_grad``): as many steps as one client's, each ``g``
+    clients wide.  ``g`` is at most ``MXU_SIDE // max(C_in, C_out)``, the
+    clients whose stacked inputs and outputs both fit one MXU tile, so
+    the blocks thrown away take no extra pass.  Of the paper's vision
+    models, LeNet-5's conv1 (3 -> 6) and conv2 (6 -> 16), ResNet-8's stem
+    (3 -> 16) and CNN-Fashion's c1 (1 -> 16) merge; every other conv,
+    CNN-FEMNIST's c1 (1 -> 32) among them, keeps the grouped gradient,
+    which is faster there.
+    """
+    w = p["w"].astype(x.dtype)
+    y = _conv_p.bind(x, w, role="y", stride=stride, padding=padding,
+                     kernel=w.shape[:2], image=x.shape[1:3])
     return y + p["b"].astype(x.dtype)
+
+
+def _conv(a, b, *, role, stride, padding, kernel, image):
+    """One model's convolution as three bilinear maps, by ``role``: "y"
+    is the convolution of ``(x, w)``, "w" its kernel gradient from
+    ``(x, dy)``, "x" its input gradient from ``(dy, w)``."""
+    def conv(x, w):
+        return jax.lax.conv_general_dilated(x, w, (stride, stride), padding,
+                                            dimension_numbers=_NHWC)
+
+    if role == "y":
+        return conv(a, b)
+    if role == "w":
+        x, dy = a, b
+        w = jax.ShapeDtypeStruct((*kernel, x.shape[-1], dy.shape[-1]), x.dtype)
+        return jax.linear_transpose(lambda w: conv(x, w), w)(dy)[0]
+    dy, w = a, b
+    x = jax.ShapeDtypeStruct((dy.shape[0], *image, w.shape[2]), dy.dtype)
+    return jax.linear_transpose(lambda x: conv(x, w), x)(dy)[0]
+
+
+def _abstract_conv(a, b, **params):
+    out = jax.eval_shape(functools.partial(_conv, **params), a, b)
+    return jax.core.ShapedArray(out.shape, out.dtype)
+
+
+def _transpose_first(ct, a, b, *, role, **params):
+    # y = conv(x, w) -> dx = X(ct, w); dw = W(x, dy) -> dx = X(dy, ct);
+    # dx = X(dy, w) -> ddy = conv(ct, w)
+    if role == "w":
+        return _conv_p.bind(b, ct, role="x", **params)
+    return _conv_p.bind(ct, b, role="x" if role == "y" else "y", **params)
+
+
+def _transpose_second(ct, a, b, *, role, **params):
+    # y = conv(x, w) -> dw = W(x, ct); dw = W(x, dy) -> ddy = conv(x, ct);
+    # dx = X(dy, w) -> dw = W(ct, dy)
+    if role == "x":
+        return _conv_p.bind(ct, a, role="w", **params)
+    return _conv_p.bind(a, ct, role="w" if role == "y" else "y", **params)
+
+
+def _batch_conv(args, dims, *, role, **params):
+    one = functools.partial(_conv, role=role, **params)
+    if role == "w" and None not in dims:
+        x, dy = (jnp.moveaxis(a, d, 0) for a, d in zip(args, dims))
+        groups = client_groups(x.shape[0], x.shape[-1], dy.shape[-1])
+        if len(groups) < x.shape[0]:
+            merged = functools.partial(_clients_kernel_grad, groups=groups,
+                                       **params)
+            with jax.named_scope("fl_conv_wgrad"):
+                return jax.lax.platform_dependent(
+                    x, dy, default=jax.vmap(one),
+                    **dict.fromkeys(MERGED_PLATFORMS, merged)), 0
+    return jax.vmap(one, in_axes=dims)(*args), 0
+
+
+_conv_p = jax.extend.core.Primitive("paper_conv")
+_conv_p.def_impl(_conv)
+_conv_p.def_abstract_eval(_abstract_conv)
+ad.defbilinear(_conv_p, _transpose_first, _transpose_second)
+batching.primitive_batchers[_conv_p] = _batch_conv
+mlir.register_lowering(_conv_p, mlir.lower_fun(_conv, multiple_results=False))
+
+
+def client_groups(n: int, c_in: int, c_out: int) -> Tuple[int, ...]:
+    """The sizes of the groups in which ``n`` clients' kernel gradients
+    are merged: as few groups as hold at most
+    ``MXU_SIDE // max(c_in, c_out)`` clients each, of sizes that differ
+    by at most one; ``n`` groups of one, the grouped gradient, unless
+    c_in < ``MERGED_C_IN`` and c_out <= ``MERGED_C_OUT``."""
+    if c_in >= MERGED_C_IN or c_out > MERGED_C_OUT:
+        return (1,) * n
+    m = -(-n // (MXU_SIDE // max(c_in, c_out)))
+    return tuple(n // m + (i < n % m) for i in range(m))
+
+
+def _clients_kernel_grad(x, dy, *, groups, stride, padding, kernel, image):
+    """The kernel gradients of ``n`` clients, ``(n, kh, kw, C_in, C_out)``,
+    from their inputs ``(n, B, H, W, C_in)`` and output gradients
+    ``(n, B, Ho, Wo, C_out)``: per group of ``g`` clients, one kernel
+    gradient over their ``g * C_in`` and ``g * C_out`` stacked channels,
+    whose diagonal blocks are the clients' own (the rest, client
+    against client, is thrown away)."""
+    c_in, c_out = x.shape[-1], dy.shape[-1]
+    out, start = [], 0
+    for g in groups:
+        xs, dys = x[start:start + g], dy[start:start + g]
+        start += g
+        dw = _conv(jnp.moveaxis(xs, 0, -2).reshape(*xs.shape[1:-1], g * c_in),
+                   jnp.moveaxis(dys, 0, -2).reshape(*dys.shape[1:-1],
+                                                    g * c_out),
+                   role="w", stride=stride, padding=padding, kernel=kernel,
+                   image=image)
+        dw = jnp.diagonal(dw.reshape(*kernel, g, c_in, g, c_out), 0, 2, 4)
+        out.append(jnp.moveaxis(dw, -1, 0))
+    return jnp.concatenate(out) if len(out) > 1 else out[0]
 
 
 def maxpool(x: jnp.ndarray, k: int = 2) -> jnp.ndarray:

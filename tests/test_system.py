@@ -138,6 +138,201 @@ def test_charlm_task_runs():
 
 
 # ---------------------------------------------------------------------------
+# paper models: the conv primitive and its clients-merged kernel gradient
+# ---------------------------------------------------------------------------
+
+NHWC = ("NHWC", "HWIO", "NHWC")
+
+# kernel, C_in, C_out, stride, image side, clients (None: one model, no
+# vmap)
+CONVS = {
+    "lenet5_conv1": (5, 3, 6, 1, 32, 3),
+    "lenet5_conv2": (5, 6, 16, 1, 16, 3),
+    "stem_3x3_stride2": (3, 3, 16, 2, 17, 3),
+    "wide_3x3x64": (3, 64, 64, 1, 8, 3),
+    "narrow_c15_two_groups": (3, 15, 8, 1, 8, 10),
+    "lenet5_conv1_one_model": (5, 3, 6, 1, 32, None),
+    "c_out32_grouped": (5, 1, 32, 1, 12, 3),
+}
+# the groups in which every conv of the paper's vision models, by its
+# params path, merges the kernel gradients of the paper's K=10 clients
+# (groups of one: the grouped gradient)
+GROUPED = (1,) * 10
+PAPER_CONV_GROUPS = {
+    "lenet5": {"c1": (10,), "c2": (5, 5)},
+    "resnet8": {"stem": (5, 5), "b1/conv1": GROUPED, "b1/conv2": GROUPED,
+                "b2/conv1": GROUPED, "b2/conv2": GROUPED,
+                "b2/proj": GROUPED, "b3/conv1": GROUPED,
+                "b3/conv2": GROUPED, "b3/proj": GROUPED},
+    "cnn_femnist": {"c1": GROUPED, "c2": GROUPED},
+    "cnn_fashion": {"c1": (5, 5), "c2": GROUPED},
+}
+
+
+def _plain_conv2d(p, x, stride=1, padding="SAME"):
+    return jax.lax.conv_general_dilated(
+        x, p["w"].astype(x.dtype), (stride, stride), padding,
+        dimension_numbers=NHWC,
+        precision=jax.lax.Precision.HIGHEST) + p["b"].astype(x.dtype)
+
+
+def _assert_trees_close(got, want, rel=1e-5):
+    for path, g in jax.tree_util.tree_flatten_with_path(got)[0]:
+        w = want
+        for k in path:
+            w = w[k.key] if hasattr(k, "key") else w[k.idx]
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
+                                   atol=rel * scale,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("case", [*CONVS, "paper_model_paths"])
+def test_conv2d_gradients_match_plain_conv(case, monkeypatch):
+    """``conv2d``'s value and its gradients in ``w``, ``b`` and ``x``,
+    vmapped over per-client kernels as the round engine runs them (and
+    for one model, as the relay runs it), against a plain
+    ``conv_general_dilated`` at HIGHEST precision: with the grouped
+    kernel gradient the CPU keeps, with the clients-merged one the TPU
+    runs (forced here), and the merged one called directly; and the
+    groups in which every paper-model conv merges its clients."""
+    from repro.models import paper_models as pm
+
+    if case == "paper_model_paths":
+        def kernels(init, key):
+            return {"/".join(str(k.key) for k in path[:-1]): leaf
+                    for path, leaf in jax.tree_util.tree_flatten_with_path(
+                        init(key))[0]
+                    if path[-1].key == "w" and getattr(leaf, "ndim", 0) == 4}
+
+        for model, want in PAPER_CONV_GROUPS.items():
+            init, apply, _ = pm.PAPER_MODELS.get(model)
+            convs = jax.eval_shape(lambda k: kernels(init, k),
+                                   jax.random.PRNGKey(0))
+            assert set(convs) == set(want), model
+            for name, w in convs.items():
+                c_in, c_out = w.shape[2:]
+                assert pm.client_groups(10, c_in, c_out) == want[name], \
+                    f"{model}/{name}"
+            # every conv of the model is the primitive
+            p = init(jax.random.PRNGKey(0))
+            x = jax.ShapeDtypeStruct((2, 28, 28, 1) if "cnn" in model
+                                     else (2, 32, 32, 3), jnp.float32)
+            jaxpr = str(jax.make_jaxpr(lambda x: apply(p, x))(x))
+            assert jaxpr.count("paper_conv[") == len(want), model
+            assert "conv_general_dilated" not in jaxpr, model
+        return
+
+    k, c_in, c_out, stride, side, clients = CONVS[case]
+    lead = () if clients is None else (clients,)
+    side_out = -(-side // stride)
+    rng = np.random.default_rng(1)
+    ps = {"w": rng.standard_normal(lead + (k, k, c_in, c_out), np.float32),
+          "b": rng.standard_normal(lead + (c_out,), np.float32)}
+    xs = rng.standard_normal(lead + (4, side, side, c_in), np.float32)
+    # a cotangent that differs at every output element
+    dys = rng.standard_normal(lead + (4, side_out, side_out, c_out),
+                              np.float32)
+
+    def value_and_grads(conv):
+        def loss(p, x, dy):
+            y = conv(p, x, stride)
+            return jnp.sum(jnp.sin(y) * dy), y
+        f = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+        (_, y), g = jax.jit(f if clients is None else jax.vmap(f))(
+            ps, xs, dys)
+        return {"y": y, "w": g[0]["w"], "b": g[0]["b"], "x": g[1]}
+
+    want = value_and_grads(_plain_conv2d)
+    _assert_trees_close(value_and_grads(pm.conv2d), want)
+    if clients is None:
+        return
+    monkeypatch.setattr(pm, "MERGED_PLATFORMS", ("tpu", "cpu"))
+    _assert_trees_close(value_and_grads(pm.conv2d), want)
+    # the merged kernel gradient, called directly
+    groups = pm.client_groups(clients, c_in, c_out)
+    assert (len(groups) < clients) == (
+        c_in < pm.MERGED_C_IN and c_out <= pm.MERGED_C_OUT), groups
+    assert sum(groups) == clients and max(groups) - min(groups) <= 1
+    got = jax.jit(lambda x, dy: pm._clients_kernel_grad(
+        x, dy, groups=groups, stride=stride, padding="SAME", kernel=(k, k),
+        image=(side, side)))(xs, dys)
+    _, vjp = jax.vjp(lambda w: jax.vmap(_plain_conv2d, (0, 0, None))(
+        dict(ps, w=w), xs, stride), ps["w"])
+    _assert_trees_close(got, vjp(dys)[0])
+
+
+def _lenet5_loss(n=4):
+    from repro.models import paper_models as pm
+    init, apply, _ = pm.PAPER_MODELS.get("lenet5")
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.standard_normal((n, 32, 32, 3), np.float32))
+    y = jnp.asarray(rng.integers(0, 10, n))
+
+    def loss(p, x=x):
+        logp = jax.nn.log_softmax(apply(p, x))
+        return -jnp.mean(logp[jnp.arange(n), y])
+    return init(jax.random.PRNGKey(0)), x, loss
+
+
+@pytest.mark.parametrize("form", ["jvp", "grad_of_grad", "hessian_top_eig",
+                                  "jvp_of_clients_grad",
+                                  "clients_local_run"])
+def test_conv2d_autodiff_forms_match_plain_conv(form, monkeypatch):
+    """Every form of autodiff goes through ``conv2d``'s primitive, on a
+    small LeNet-5, and agrees with the model built on plain
+    ``conv_general_dilated``: forward mode, reverse over reverse, the
+    Fig 7 Hessian probe (forward over reverse), forward over the
+    clients' vmapped gradient and, through ``make_local_fn``'s scan,
+    the vmapped local run, each with the clients-merged kernel gradient
+    the TPU runs."""
+    from repro.core import diagnostics as diag
+    from repro.models import paper_models as pm
+
+    p, x, loss = _lenet5_loss()
+    K = 3
+    ps = jax.tree_util.tree_map(
+        lambda a: jnp.stack([a * (1 + 0.1 * i) for i in range(K)]), p)
+    xs = jnp.stack([x * (1 + 0.2 * i) for i in range(K)])
+    leaves = jax.tree_util.tree_leaves
+
+    def run():
+        if form == "jvp":
+            return jax.jit(lambda t: jax.jvp(loss, (p,), (t,)))(p)
+        if form == "grad_of_grad":
+            return jax.jit(jax.grad(lambda p: sum(
+                jnp.sum(g ** 2) for g in leaves(jax.grad(loss)(p)))))(p)
+        if form == "hessian_top_eig":
+            return diag.hessian_top_eig(loss, p, jax.random.PRNGKey(3),
+                                        n_iter=4)
+        if form == "jvp_of_clients_grad":
+            return jax.jit(lambda t: jax.jvp(
+                jax.vmap(jax.grad(loss)), (ps, xs),
+                (t, jnp.zeros_like(xs))))(ps)
+        from repro.fl.local import LocalSpec, make_local_fn
+        from repro.fl.task import vision_task
+        local = make_local_fn(vision_task("lenet5"),
+                              LocalSpec(n_steps=3, batch_size=4, lr=0.05))
+        keys = jax.random.split(jax.random.PRNGKey(4), K)
+        ys = jnp.asarray(np.random.default_rng(5).integers(0, 10, (K, 8)))
+        cx = jnp.concatenate([xs, xs[:, ::-1]], axis=1)
+        return jax.jit(jax.vmap(lambda k, x, y: local(
+            k, p, {}, x, y, jnp.float32(1.0))))(keys, cx, ys)
+
+    got = run()
+    monkeypatch.setattr(pm, "MERGED_PLATFORMS", ("tpu", "cpu"))
+    merged = run()
+    monkeypatch.setattr(pm, "conv2d", _plain_conv2d)
+    want = run()
+    if form == "hessian_top_eig":
+        assert got > 0
+        np.testing.assert_allclose([got, merged], [want, want], rtol=1e-4)
+        return
+    _assert_trees_close(got, want, rel=1e-4)
+    _assert_trees_close(merged, want, rel=1e-4)
+
+
+# ---------------------------------------------------------------------------
 # pod-scale (sharded) driver
 # ---------------------------------------------------------------------------
 
