@@ -19,12 +19,19 @@ program's place:
 A step that returns its state unchanged, or applies its update twice,
 reads 1 on ``update1`` by the measure itself and needs no run.
 
+With ``--faults`` the program itself runs broken instead, for a fault
+that only exists in its own layout:
+
+  drop_exchange  the cross-pod combine sums each chip's own pod's lanes
+                 and leaves out the ``psum`` over the mesh's ``data``
+
 Each seed prints one JSON line; ``cells/<cell>.json`` keeps the limits
 set from them.  The benchmark's own runs never run this.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import functools
 import json
 import pathlib
@@ -75,6 +82,41 @@ def readings_of(h: Harness, seed: int, controls: bool) -> dict:
     return out
 
 
+def own_pod_only(self, acc_bufs):
+    """``ShardedFlatOps.lane_combine`` with the exchange between the
+    pods left out."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from repro.sharding import rules
+    run = jax.shard_map(lambda a: jnp.sum(a, axis=0), mesh=self.mesh,
+                        in_specs=(rules.lane_axis_pspec(),),
+                        out_specs=P(None, None), check_vma=False)
+    return {name: run(acc) for name, acc in acc_bufs.items()}
+
+
+def fault_sites():
+    """``{fault: (owner, attribute, replacement)}``."""
+    from repro.fl.pod import ShardedFlatOps
+    return {"drop_exchange": (ShardedFlatOps, "lane_combine", own_pod_only)}
+
+
+@contextlib.contextmanager
+def planted(fault: str):
+    """The program with ``fault`` in place, its round programs traced
+    anew on the way in and out."""
+    from repro.fl import engine
+    owner, attr, fn = fault_sites()[fault]
+    old = getattr(owner, attr)
+    engine._cached_chunk_fn.cache_clear()
+    setattr(owner, attr, fn)
+    try:
+        yield
+    finally:
+        setattr(owner, attr, old)
+        engine._cached_chunk_fn.cache_clear()
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -82,10 +124,23 @@ def main(argv=None) -> int:
     ap.add_argument("--controls", type=int, default=3,
                     help="seeds (the first ones) that also run the control "
                          "and the planted faults")
+    ap.add_argument("--faults", nargs="+", default=(),
+                    help="run the program broken by each of these instead")
     args = ap.parse_args(argv)
     cell = load_cell(args.workload)
     devs = chip_devices(cell.chips)
     use_compile_cache()
+    for fault in args.faults:
+        with planted(fault):
+            h = Harness(cell, devs)
+            for seed in args.seeds:
+                t0 = time.perf_counter()
+                out = readings_of(h, seed, controls=False)
+                out["fault"] = fault
+                out["seconds"] = time.perf_counter() - t0
+                print(json.dumps(out), flush=True)
+    if args.faults:
+        return 0
     h = Harness(cell, devs)
     for i, seed in enumerate(args.seeds):
         t0 = time.perf_counter()

@@ -62,7 +62,7 @@ for _p in (str(ROOT / "src"), str(ROOT)):
 
 import numpy as np                                            # noqa: E402
 
-from bench import compare, peaks, traffic, xtrace             # noqa: E402
+from bench import compare, peaks, scopes, traffic, xtrace     # noqa: E402
 
 CACHE_DIR = ROOT / ".jax_cache"
 WINDOW = "bench.window"          # the profiler span around the window
@@ -255,22 +255,35 @@ def _fields(cls, d: dict) -> dict:
     return {k: v for k, v in d.items() if k in names}
 
 
+def cell_mesh(t: dict, devs):
+    """The pod backend's mesh on the cell's chips: the traffic's
+    ``mesh``, ``[data, model]`` (``data`` rows of ``model`` chips, one
+    pod a row under hierarchical aggregation), ``[1, 1]`` where it names
+    none."""
+    from repro.launch.mesh import make_device_mesh
+    data, model = t.get("mesh", (1, 1))
+    if data * model != len(devs):
+        raise ValueError(f"traffic mesh {[data, model]} does not hold the "
+                         f"cell's {len(devs)} chips")
+    return make_device_mesh(model=model, devices=devs)
+
+
 def build_round_loop(t: dict, devs):
     """``(strategy, schedule)`` of the traffic's phase, built from its
     ``fl`` part the way ``run_pod_training`` and ``run_federated`` build
-    theirs, on one chip."""
+    theirs, on the cell's chips (:func:`cell_mesh`); the host backend
+    runs on one chip."""
     fl = t["fl"]
-    if len(devs) != 1:
-        raise ValueError(f"the round loop is built for one chip, the cell "
-                         f"asks for {len(devs)}")
     if t["backend"] == "pod":
         from repro.fl.pod import PodFLConfig, PodFLSpec
-        from repro.launch.mesh import make_host_mesh
-        cfg = PodFLConfig(mesh=make_host_mesh(),
+        cfg = PodFLConfig(mesh=cell_mesh(t, devs),
                           spec=PodFLSpec(**_fields(PodFLSpec, fl)),
                           **_fields(PodFLConfig, dict(fl, rounds=1)))
     elif t["backend"] == "host":
         from repro.fl.simulation import FLConfig
+        if len(devs) != 1:
+            raise ValueError(f"the host backend runs on one chip, the cell "
+                             f"asks for {len(devs)}")
         cfg = FLConfig(**_fields(FLConfig, dict(fl, rounds=1)))
     else:
         raise ValueError(f"unknown backend {t['backend']!r}")
@@ -483,11 +496,22 @@ class Context:
     traces_in_window: int
     flops_per_round: float
     params: int                 # logical parameter count
+    chip_params: int            # of them, what falls to one chip
     param_bytes: int            # bytes per stored parameter
     clients_per_round: int
     local_steps: int
     chips: int
     peaks: peaks.Peaks
+    scopes: Optional[scopes.Phases] = None      # the round program's phases
+
+
+def chip_params(params: int, chips: int) -> int:
+    """Parameter elements of one client's update that one chip's kernel
+    must touch: all of them on one chip, an even share of them on a mesh
+    whose chips split each client's flat buffers.  Calls times this is
+    the least work of each chip's kernel, however the program lays the
+    shards out."""
+    return -(-params // chips)
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
@@ -600,10 +624,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                           for m in cell.end_to_end}
     else:
         tr = xtrace.load(trace_dir)
+        phases = scopes.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
         span_ev = tr.annotation(WINDOW)
         win = (span_ev.start_ns, span_ev.end_ns)
         cores = [tr.device_ops[c] for c in sorted(tr.device_ops)][:cell.chips]
+        n_params = h.ref.n_params(h.model)
         busy = float(np.mean([xtrace.busy_ns(ev, win) for ev in cores]))
         ctx = Context(
             trace=tr, window=win, window_s=window_s, rounds=R,
@@ -611,12 +637,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             compiles_in_window=compiles_in_window,
             traces_in_window=traces_in_window,
             flops_per_round=h.flops_per_round(),
-            params=h.ref.n_params(h.model),
+            params=n_params, chip_params=chip_params(n_params, cell.chips),
             param_bytes=np.dtype(h.num.carrier).itemsize,
             clients_per_round=h.K, local_steps=int(h.fl["local_steps"]),
             chips=cell.chips,
             peaks=peaks.peaks_of(d0.device_kind) if require_chip
-            else peaks.PEAKS["TPU v5 lite"])
+            else peaks.PEAKS["TPU v5 lite"], scopes=phases)
         metrics = {}
         for m in cell.per_layer:
             v = load_module(cell.dirs, "metrics", m["name"]).read(ctx)

@@ -11,23 +11,29 @@ plane, read from :class:`xtrace.Trace` as it is; the span metrics
 
 Phases.  Every op of the round program (``jit_chunk``) carries its
 ``jax.named_scope`` path in its ``op_name`` metadata; the innermost
-``fl_*`` name in it is the op's phase (``fl_fwd_bwd``, ``fl_unflatten``,
-``fl_step_tail``, ``fl_aggregate``, ``fl_server_update``, ``fl_eval``),
-and an op under none is carry plumbing (``unscoped``).  A TPU op event
+``fl_*`` name in it is the op's phase (``fl_fwd_bwd``, ``fl_conv_wgrad``,
+``fl_unflatten``, ``fl_step_tail``, ``fl_aggregate``,
+``fl_server_update``, ``fl_eval``), and an op under none is carry
+plumbing (``unscoped``).  Each op also keeps its instruction's kind, so
+that a phase's collectives (``COLLECTIVES``) read apart from its
+compute: on a mesh the cross-pod combine and the tensor-parallel
+all-reduces are the same kind of op, told apart by their phase.  A TPU op event
 names only its instruction; :func:`load` maps each instruction to its
 ``op_name`` through the compiled program's HLO, which the profiler keeps
-in the trace file (:func:`_hlo_op_names`), and takes the program's ops
+in the trace file (:func:`_hlo_instructions`), and takes the program's ops
 from its ``XLA Modules`` events.  A fusion carries the ``op_name`` that
 XLA gave it, its root's.  The reductions take a window and, like
 ``xtrace.self_ns``, count each op's own time, less its children's.
-The phases need the trace file itself, so no per-layer metric reads
-them; :func:`main` prints them for a trace on disk::
+The phases need the trace file itself: ``bench/run.py`` loads them
+into its readers' context before it deletes the trace, and the
+``*_device_ms_per_round`` and collective readers read them through
+:func:`ms_per_round`; :func:`main` prints them for a trace on disk::
 
     python -m repro.launch.train ... --trace-dir DIR
     python3 -m bench.scopes DIR --rounds <rounds in the trace>
 
 A program without these names (an engine before them) gives no spans
-and no phases: the span metrics then return None.
+and no phases: the span and phase metrics then return None.
 """
 from __future__ import annotations
 
@@ -41,9 +47,14 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from bench import xtrace
 
 ENGINE = ("engine.", "store.")
-PHASES = ("fl_fwd_bwd", "fl_unflatten", "fl_step_tail", "fl_aggregate",
-          "fl_server_update", "fl_eval")
+PHASES = ("fl_fwd_bwd", "fl_conv_wgrad", "fl_unflatten", "fl_step_tail",
+          "fl_aggregate", "fl_server_update", "fl_eval")
 UNSCOPED = "unscoped"
+# instruction kinds of the collectives, each also as its async pair
+COLLECTIVES = frozenset(k + form for k in ("all-reduce", "all-gather",
+                                           "reduce-scatter",
+                                           "collective-permute")
+                        for form in ("", "-start", "-done"))
 PROGRAM = "jit_chunk("              # its runs: jit_chunk(<id>)
 MODULES = "XLA Modules"             # a TPU core's line of program runs
 _PHASE = re.compile(r"\bfl_[a-z_]+")
@@ -125,24 +136,59 @@ def phase_of(op_name: str) -> str:
     return hits[-1] if hits else UNSCOPED
 
 
+@dataclasses.dataclass(frozen=True)
+class Op(xtrace.Event):
+    """A round-program op event named by its phase, with the kind of
+    its instruction (``xtrace.op_kind``: ``all-reduce-start``)."""
+    kind: str = ""
+
+
 @dataclasses.dataclass
 class Phases:
-    """The round program's op events of the first device, each named by
-    its phase (an ``xtrace.Event`` whose name is the phase), and the
-    program's own intervals (its ``XLA Modules`` events)."""
+    """The round program's op events of the first device, each an
+    :class:`Op` named by its phase, and the program's own intervals (its
+    ``XLA Modules`` events)."""
     ops: List[xtrace.Event]
     runs: List[xtrace.Event]
 
-    def self_ms(self, window: xtrace.Window) -> Dict[str, float]:
+    def self_ms(self, window: xtrace.Window, collective: bool = False
+                ) -> Dict[str, float]:
         """Own device ms of the round program's ops by phase in
-        ``window``."""
-        return {k: v * 1e-6 for k, v in xtrace.self_ns(self.ops,
-                                                       window).items()}
+        ``window``; with ``collective``, of its collective ops alone
+        (the others still own their time, so that nesting counts
+        once)."""
+        ops = self.ops
+        if collective:
+            ops = [dataclasses.replace(
+                e, name=e.name if getattr(e, "kind", "") in COLLECTIVES
+                else "") for e in ops]
+        own = {k: v * 1e-6 for k, v in xtrace.self_ns(ops, window).items()}
+        own.pop("", None)
+        return own
 
     def run_ms(self, window: xtrace.Window) -> float:
         """Device ms the round program ran in ``window``, from its
         module events: the sum the phases should come to."""
         return xtrace.busy_ns(self.runs, window) * 1e-6
+
+
+def ms_per_round(ctx, phases: Sequence[str], collective: bool = False
+                 ) -> Optional[float]:
+    """Own device ms per round of a reader context's round-program ops
+    in ``phases`` (``UNSCOPED`` may be one), or of their collectives
+    alone; None without the program's phases (no trace, no round
+    program, or a program that names none) or where no op of
+    ``phases`` ran."""
+    ph = getattr(ctx, "scopes", None)
+    if ph is None or not ctx.rounds:
+        return None
+    own = ph.self_ms(ctx.window)
+    if not own.keys() & set(PHASES):
+        return None
+    if collective:
+        own = ph.self_ms(ctx.window, collective=True)
+    hits = [v for k, v in own.items() if k in phases]
+    return sum(hits) / ctx.rounds if hits else None
 
 
 def report(trace: xtrace.Trace, window: xtrace.Window,
@@ -151,7 +197,8 @@ def report(trace: xtrace.Trace, window: xtrace.Window,
     """The engine's spans in ``window`` with the device-idle ms inside
     each, the longest idle gaps with their span, and the round program's
     device ms by phase (per round when ``rounds`` is given), beside the
-    program's own run time: the phases partition it."""
+    program's own run time (the phases partition it), and the part of
+    each phase's time that its collectives took."""
     out = file or sys.stdout
     if trace.device_ops:
         print("span: count, host ms, device-idle ms inside", file=out)
@@ -169,6 +216,11 @@ def report(trace: xtrace.Trace, window: xtrace.Window,
                         sorted(own.items(), key=lambda kv: -kv[1])) +
               f"; sum {sum(own.values()) / per:.3f}, program ran "
               f"{phases.run_ms(window) / per:.3f}", file=out)
+        coll = phases.self_ms(window, collective=True)
+        print("  of it collectives: " + (", ".join(
+            f"{k} {v / per:.3f}" for k, v in
+            sorted(coll.items(), key=lambda kv: -kv[1])) or "none"),
+            file=out)
 
 
 def main(argv=None) -> int:
@@ -202,18 +254,22 @@ def load(trace_dir: str) -> Optional[Phases]:
     path = xtrace.find_xplane(trace_dir)
     with open(path, "rb") as f:
         raw = f.read()
-    names = {m: ops for m, ops in _hlo_op_names(raw).items()
-             if m.startswith(PROGRAM)}
-    if not names:
+    instrs = {m: ops for m, ops in _hlo_instructions(raw).items()
+              if m.startswith(PROGRAM)}
+    if not instrs:
         return None
+    names = {m: {i: v[0] for i, v in ops.items()}
+             for m, ops in instrs.items()}
+    opcodes = {m: {i: v[1] for i, v in ops.items()}
+               for m, ops in instrs.items()}
     pd = ProfileData.from_serialized_xspace(raw)
-    ops, runs = _tpu_ops(pd, names)
+    ops, runs = _tpu_ops(pd, names, opcodes)
     if ops is None:
-        ops, runs = _cpu_ops(pd, names)
+        ops, runs = _cpu_ops(pd, names, opcodes)
     return Phases(ops=sorted(ops, key=lambda e: e.start_ns), runs=runs)
 
 
-def _tpu_ops(pd, names):
+def _tpu_ops(pd, names, opcodes):
     """The first TPU core's phases, or None without a TPU plane."""
     planes = sorted((int(m.group(1)), p) for p in pd.planes
                     for m in [xtrace.DEVICE_PLANE.match(p.name)] if m)
@@ -224,17 +280,22 @@ def _tpu_ops(pd, names):
         return [], []
     return phase_ops([xtrace._event(e) for e in lines[MODULES].events],
                      [xtrace._event(e) for e in lines[xtrace.OPS_LINE].events],
-                     names)
+                     names, opcodes)
 
 
 def phase_ops(modules: Sequence[xtrace.Event], ops: Sequence[xtrace.Event],
-              names: Dict[str, Dict[str, str]]
+              names: Dict[str, Dict[str, str]],
+              opcodes: Optional[Dict[str, Dict[str, str]]] = None
               ) -> Tuple[List[xtrace.Event], List[xtrace.Event]]:
     """``(ops, runs)``: the op events that lie inside a run of a program
     in ``names`` (``{program: {instruction: op_name}}``), each renamed
     to its phase, and those runs.  ``modules`` are one core's ``XLA
     Modules`` events, named ``<program>(<id>)`` like ``names``' keys;
-    ``ops`` its ``XLA Ops`` events, named by their instruction's text."""
+    ``ops`` its ``XLA Ops`` events, named by their instruction's text.
+    Each op's kind is its instruction's opcode from ``opcodes`` (same
+    keys as ``names``; a collective may carry the name of the JAX op it
+    lowers, ``%psum.22 = ... all-reduce(``), else the kind of its
+    name."""
     import bisect
     mods = sorted(modules, key=lambda e: e.start_ns)
     starts = [m.start_ns for m in mods]
@@ -243,12 +304,15 @@ def phase_ops(modules: Sequence[xtrace.Event], ops: Sequence[xtrace.Event],
         i = bisect.bisect_right(starts, e.start_ns) - 1
         if i < 0 or mods[i].name not in names or e.end_ns > mods[i].end_ns:
             continue
-        op = names[mods[i].name].get(xtrace.op_name(e), "")
-        out.append(xtrace.Event(phase_of(op), e.start_ns, e.dur_ns))
+        instr = xtrace.op_name(e)
+        op = names[mods[i].name].get(instr, "")
+        kind = (opcodes or {}).get(mods[i].name, {}).get(instr)
+        out.append(Op(phase_of(op), e.start_ns, e.dur_ns,
+                      kind or xtrace.op_kind(instr)))
     return out, [m for m in mods if m.name in names]
 
 
-def _cpu_ops(pd, names):
+def _cpu_ops(pd, names, opcodes):
     """JAX's CPU backend runs a program's ops on host threads, each
     event naming its instruction, module and program id."""
     ops = []
@@ -260,24 +324,27 @@ def _cpu_ops(pd, names):
                 st = dict(e.stats)
                 mod = f"{st.get('hlo_module')}({st.get('program_id')})"
                 if "hlo_op" in st and mod in names:
-                    ops.append(xtrace.Event(
-                        phase_of(names[mod].get(st["hlo_op"], "")),
-                        float(e.start_ns), float(e.duration_ns)))
+                    instr = st["hlo_op"]
+                    ops.append(Op(
+                        phase_of(names[mod].get(instr, "")),
+                        float(e.start_ns), float(e.duration_ns),
+                        opcodes[mod].get(instr) or xtrace.op_kind(instr)))
     return ops, list(ops)
 
 
-def _hlo_op_names(raw: bytes) -> Dict[str, Dict[str, str]]:
-    """``{program: {instruction: op_name}}`` from the HLO protos that the
-    profiler keeps in the trace's ``/host:metadata`` plane (XSpace.planes
-    -> XPlane.event_metadata -> XEventMetadata.stats -> HloProto ->
-    HloModuleProto.computations -> HloInstructionProto{name, metadata}),
-    read field by field so that no protobuf schema is needed."""
+def _hlo_instructions(raw: bytes) -> Dict[str, Dict[str, Tuple[str, str]]]:
+    """``{program: {instruction: (op_name, opcode)}}`` from the HLO
+    protos that the profiler keeps in the trace's ``/host:metadata``
+    plane (XSpace.planes -> XPlane.event_metadata -> XEventMetadata.stats
+    -> HloProto -> HloModuleProto.computations ->
+    HloInstructionProto{name, opcode, metadata}), read field by field so
+    that no protobuf schema is needed."""
     buf = memoryview(raw)
 
     def text(span):
         return bytes(buf[span[0]:span[1]]).decode()
 
-    out: Dict[str, Dict[str, str]] = {}
+    out: Dict[str, Dict[str, Tuple[str, str]]] = {}
     for plane in _sub(buf, (0, len(buf)), 1):
         if [text(s) for s in _sub(buf, plane, 2)] != ["/host:metadata"]:
             continue
@@ -292,9 +359,10 @@ def _hlo_op_names(raw: bytes) -> Dict[str, Dict[str, str]]:
                                 for ins in _sub(buf, comp, 2):
                                     name = "".join(
                                         text(s) for s in _sub(buf, ins, 1))
-                                    ops[name] = "".join(
+                                    ops[name] = ("".join(
                                         text(s) for m in _sub(buf, ins, 7)
-                                        for s in _sub(buf, m, 2))
+                                        for s in _sub(buf, m, 2)), "".join(
+                                        text(s) for s in _sub(buf, ins, 2)))
     return out
 
 

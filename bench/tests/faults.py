@@ -14,6 +14,9 @@ one-chip training cell can have:
               every dispatch of a call draws the first one's clients and
               batches again
 
+and, on a mesh of pods (``test_bench_mesh.py``), ``controls.py``'s
+``drop_exchange``: the cross-pod combine without its exchange
+
 and the control, the plain reference in the nearest precision below the
 configuration's, put in the program's place, fails too, as do the
 reference's own half-batch and stale-key variants that ``controls.py``
@@ -27,16 +30,16 @@ import jax.numpy as jnp
 import pytest
 
 from bench import compare, run
-from bench.controls import readings_of
+from bench.controls import fault_sites, readings_of
 
 # the tiny stand-ins of each cell: its own config or a narrower one, and
 # its own traffic with fewer clients, rows and steps
+TINY_LM = {"hidden_size": 64, "intermediate_size": 128,
+           "num_hidden_layers": 2, "num_attention_heads": 4,
+           "num_key_value_heads": 4, "head_dim": 16, "vocab_size": 256}
 TINY = {
     "qwen05b.p2-fedavg": dict(
-        config={"hidden_size": 64, "intermediate_size": 128,
-                "num_hidden_layers": 2, "num_attention_heads": 4,
-                "num_key_value_heads": 4, "head_dim": 16,
-                "vocab_size": 256},
+        config=TINY_LM,
         data={"clients": 4, "sequences_per_client": 8, "seq_len": 16},
         fl={"clients_per_round": 2, "local_steps": 2}),
     "lenet5.p2-fedavg": dict(
@@ -107,6 +110,8 @@ def plant(fault: str, monkeypatch) -> None:
                     return (key,) + tuple(chunk(key, *args)[1:])
                 return _jit(self, same_key, task, n_clients)
             monkeypatch.setattr(cls, "jit_chunk", stale)
+    elif fault in fault_sites():
+        monkeypatch.setattr(*fault_sites()[fault])
     else:
         raise ValueError(fault)
 

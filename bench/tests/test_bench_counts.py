@@ -51,3 +51,56 @@ def test_peaks_are_keyed_by_device_kind():
     assert peaks.peaks_of("TPU v5 lite").hbm_bw == 819e9
     with pytest.raises(ValueError, match="no published peaks"):
         peaks.peaks_of("TPU v99")
+
+
+DATA = ROOT / "bench" / "tests" / "data"
+# the roofline readers' readings on the recorded one-chip traces before
+# they took the chip's share: one call of LeNet-5's fused_local_step
+ONE_CHIP = {"trace_lenet_v5e": 90.54878905894708,
+            "trace_scopes_v5e": 90.67159483072685}
+
+
+def _roofline_ctx(ops, window, params, chips, param_bytes):
+    import types
+    from bench import peaks, xtrace
+    return types.SimpleNamespace(
+        trace=xtrace.Trace({0: ops}, []), window=window, rounds=1,
+        clients_per_round=1, local_steps=1, param_bytes=param_bytes,
+        params=params, chip_params=run.chip_params(params, chips),
+        chips=chips, peaks=peaks.PEAKS["TPU v5 lite"])
+
+
+def _reader(name):
+    return run.load_module([run.BENCH], "metrics", name).read
+
+
+@pytest.mark.parametrize("trace", sorted(ONE_CHIP))
+def test_rooflines_on_one_chip_read_as_before(trace):
+    from bench import xtrace
+    from bench.reference import lenet5
+    fx = json.loads((DATA / f"{trace}.json").read_text())
+    ops = [xtrace.Event(*o) for o in fx["device_ops"]]
+    n = lenet5.n_params(lenet5.from_config(_cfg("lenet5-cifar10")))
+    one = _roofline_ctx(ops, tuple(fx["window"]), n, 1, 4)
+    assert one.chip_params == n
+    assert _reader("local_step_roofline")(one) == pytest.approx(
+        ONE_CHIP[trace], rel=1e-12)
+    assert _reader("delta_accum_roofline")(one) is None
+    # the same events on a chip of four hold a quarter of the work
+    four = _roofline_ctx(ops, tuple(fx["window"]), n, 4, 4)
+    assert four.chip_params == -(-n // 4)
+    assert _reader("local_step_roofline")(four) == pytest.approx(
+        ONE_CHIP[trace] * four.chip_params / n, rel=1e-12)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_delta_accum_roofline_by_hand(chips):
+    from bench import xtrace
+    n = 463_987_712                     # qwen1.5-0.5b, bf16 params
+    ops = [xtrace.Event("%fused_delta_accum.3 = f32[...] custom-call(",
+                        1e6, 8.5e6)]
+    ctx = _roofline_ctx(ops, (0.0, 2e7), n, chips, 2)
+    # f32 delta read and written, end and round params read at 2 bytes
+    least = (4 + 2 + 2 + 4) * -(-n // chips) / 819e9
+    assert _reader("delta_accum_roofline")(ctx) == pytest.approx(
+        100 * least / 8.5e-3)

@@ -17,7 +17,15 @@ import pytest
 from bench import run, scopes, xtrace
 
 DATA = pathlib.Path(__file__).parent / "data" / "trace_scopes_v5e.json"
-METRICS = ("engine_pack_ms_per_call", "engine_unpack_ms_per_call")
+METRICS = ("engine_pack_ms_per_call", "engine_unpack_ms_per_call",
+           "fwd_bwd_device_ms_per_round", "unflatten_device_ms_per_round",
+           "update_device_ms_per_round", "unscoped_device_ms_per_round")
+# each phase reader's phases
+READS = {"fwd_bwd_device_ms_per_round": ("fl_fwd_bwd", "fl_conv_wgrad"),
+         "unflatten_device_ms_per_round": ("fl_unflatten",),
+         "update_device_ms_per_round": ("fl_step_tail", "fl_aggregate",
+                                        "fl_server_update"),
+         "unscoped_device_ms_per_round": (scopes.UNSCOPED,)}
 
 
 def _ctx(fx, window, named=True):
@@ -68,9 +76,11 @@ def test_device_phases_match_brute_force(fx):
     got = ctx.scopes.self_ms(ctx.window)
     for k, v in want.items():
         assert got.get(k, 0.0) == pytest.approx(v, abs=5e-6), k
-    # every phase of the lenet5 round shows in this stretch
+    # every phase of the lenet5 round shows in this stretch; it was
+    # recorded before the model step named its merged conv kernel
+    # gradients fl_conv_wgrad, and lenet5 runs no server optimizer
     assert {k for k, v in want.items() if v > 0} == set(scopes.PHASES) - {
-        "fl_server_update"} | {scopes.UNSCOPED}
+        "fl_server_update", "fl_conv_wgrad"} | {scopes.UNSCOPED}
 
 
 def test_phases_sum_to_the_program_run(fx):
@@ -78,6 +88,25 @@ def test_phases_sum_to_the_program_run(fx):
     own = ctx.scopes.self_ms(ctx.window)
     assert sum(own.values()) == pytest.approx(ctx.scopes.run_ms(ctx.window),
                                               rel=0.02)
+
+
+@pytest.mark.parametrize("metric", sorted(READS))
+def test_phase_readers_match_brute_force(fx, metric):
+    ctx = _ctx(fx, fx["window"])
+    want = _own_by_brute_force(fx)
+    assert _read(metric, ctx) == pytest.approx(
+        sum(want[k] for k in READS[metric]) / fx["rounds"], abs=1e-5)
+
+
+def test_phase_readers_sum_to_the_program_run(fx):
+    # fl_eval, the one phase no reader takes, ran 0.145 of lenet5's
+    # 13.18 device ms a round on the chip
+    ctx = _ctx(fx, fx["window"])
+    got = sum(_read(m, ctx) for m in READS) * fx["rounds"]
+    own = ctx.scopes.self_ms(ctx.window)
+    assert got == pytest.approx(sum(own.values()) - own.get("fl_eval", 0.0),
+                                rel=1e-9)
+    assert got == pytest.approx(ctx.scopes.run_ms(ctx.window), rel=0.02)
 
 
 def test_span_readers_match_the_spans(fx):
@@ -170,13 +199,18 @@ def test_load_reads_op_names_from_a_profiler_trace(tmp_path):
     d = _profile(tmp_path, fn, *args)
     raw = pathlib.Path(xtrace.find_xplane(d)).read_bytes()
     # every round program alive in the process is in the trace's metadata
-    names = {m: o for m, o in scopes._hlo_op_names(raw).items()
+    instrs = scopes._hlo_instructions(raw)
+    names = {m: {i: v[0] for i, v in o.items()} for m, o in instrs.items()
              if m.startswith(scopes.PROGRAM)}
     # the wire-format reader agrees with the compiled program's text
     text = fn.lower(*args).compile().as_text()
     want = dict(re.findall(r'%(\S+) = .*op_name="([^"]*)"', text))
     assert want and any({k: got.get(k) for k in want} == want
                         for got in names.values())
+    # and each instruction's opcode, where its shape is no tuple
+    codes = dict(re.findall(r'%(\S+) = [^ (]+ ([a-z][a-z0-9-]*)\(', text))
+    assert codes and any({k: ops.get(k, ("", ""))[1] for k in codes} == codes
+                         for ops in instrs.values())
     ph = scopes.load(d)
     tr = xtrace.load(d)
     win = (tr.annotation("bench.window").start_ns,
@@ -211,3 +245,61 @@ def test_command_line_needs_engine_spans(tmp_path, capsys):
     d = _profile(tmp_path, _chunk(True), jnp.ones(256), jnp.ones((8, 16)))
     assert scopes.main([d]) == 1
     assert "no engine spans" in capsys.readouterr().err
+
+
+def _mesh_ctx(rounds=2):
+    """A round program of one module run with a while loop, made up by
+    hand: the combine's async all-reduce under fl_aggregate, the
+    tensor-parallel all-reduces and an all-gather under the model's
+    phases, one collective-permute unscoped, and compute around them."""
+    prog = "jit_chunk(7)"
+    names = {prog: {
+        "while.1": "jit(chunk)/while",
+        "fusion.2": "jit(chunk)/while/body/fl_fwd_bwd/dot_general",
+        "all-reduce.3": "jit(chunk)/while/body/fl_fwd_bwd/psum",
+        "all-gather-start.4":
+            "jit(chunk)/while/body/transpose(jvp(fl_unflatten))/all_gather",
+        "all-gather-done.4":
+            "jit(chunk)/while/body/transpose(jvp(fl_unflatten))/all_gather",
+        "collective-permute.5": "jit(chunk)/while/body/ppermute",
+        "all-reduce-start.6": "jit(chunk)/fl_aggregate/shard_map/psum",
+        "all-reduce-done.6": "jit(chunk)/fl_aggregate/shard_map/psum",
+        "fused_delta_accum.7": "jit(chunk)/fl_aggregate/fused_delta_accum",
+        "psum.8": "jit(chunk)/fl_aggregate/shard_map/psum",
+    }}
+    # the combine's all-reduce carries the name of the JAX op it lowers
+    opcodes = {prog: {"psum.8": "all-reduce", "fusion.2": "fusion"}}
+    ops = [xtrace.Event(f"%{i} = f32[8] op(", s, d) for i, s, d in [
+        ("while.1", 0, 500), ("fusion.2", 10, 100), ("all-reduce.3", 120, 30),
+        ("all-gather-start.4", 160, 5), ("all-gather-done.4", 170, 20),
+        ("collective-permute.5", 200, 40), ("fusion.2", 250, 100),
+        ("all-reduce.3", 360, 30), ("fused_delta_accum.7", 520, 50),
+        ("all-reduce-start.6", 580, 4), ("all-reduce-done.6", 590, 60),
+        ("psum.8", 660, 20)]]
+    phase_ops, runs = scopes.phase_ops([xtrace.Event(prog, 0, 700)], ops,
+                                       names, opcodes)
+    return types.SimpleNamespace(trace=xtrace.Trace({0: ops}, []),
+                                 window=(0.0, 700.0), rounds=rounds,
+                                 scopes=scopes.Phases(phase_ops, runs))
+
+
+def test_collective_readers_split_the_combine_from_tp():
+    ctx = _mesh_ctx()
+    # ms per round: the combine's start and done; every other collective
+    assert _read("combine_ms_per_round", ctx) == pytest.approx(
+        (4 + 60 + 20) * 1e-6 / 2)
+    assert _read("tp_collective_ms_per_round", ctx) == pytest.approx(
+        (30 + 5 + 20 + 40 + 30) * 1e-6 / 2)
+    # the while owns what its ops leave, collectives included
+    own = ctx.scopes.self_ms(ctx.window)
+    assert own["unscoped"] == pytest.approx((500 - 325 + 40) * 1e-6)
+    assert sum(own.values()) == pytest.approx(
+        xtrace.busy_ns(ctx.trace.device_ops[0], ctx.window) * 1e-6)
+
+
+def test_collective_readers_read_none_without_collectives_or_names(fx):
+    ctx = _ctx(fx, fx["window"])
+    for metric in ("combine_ms_per_round", "tp_collective_ms_per_round"):
+        assert _read(metric, ctx) is None, metric
+        assert _read(metric, types.SimpleNamespace(
+            window=ctx.window, rounds=2, scopes=None)) is None, metric
